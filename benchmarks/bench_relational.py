@@ -11,12 +11,9 @@ pass --scale to grow them.
 """
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
-import textwrap
-
+import jax
 import numpy as np
+from jax.sharding import Mesh
 
 from repro import hiframes as hf
 from repro.data import synth
@@ -131,79 +128,52 @@ def bench_groupby_partialagg(n):
 
 
 # Fig. 13 (repo extension) — zipf-skew join, salted vs stats-blind planning.
-# Runs in a subprocess at a FIXED 8 fake host devices so the skew actually
-# lands on shards regardless of the parent bench environment; one process
-# measures both arms so they share data, compile cache state and machine
-# noise.  The baseline gets shuffle_slack doubled iff default slack overflows
-# the hot bucket (the steady state the overflow-retry driver reaches on this
-# distribution); the salted arm runs adaptive defaults.
-_SKEW_SCRIPT = """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import time
-import numpy as np
-from repro import hiframes as hf
-
-n, m = {n}, {m}
-rng = np.random.default_rng(13)
-k = rng.integers(0, m, n).astype(np.int32)
-k[: int(0.30 * n)] = 3          # one zipf-hot key: ~30% of all probe rows
-rng.shuffle(k)
-probe = {{"k": k, "v": rng.normal(size=n).astype(np.float32)}}
-dim = {{"k": np.arange(m, dtype=np.int32),
-        "w": rng.normal(size=m).astype(np.float32)}}
-j = hf.table(probe, "probe").merge(hf.table(dim, "dim"), on="k")
-
-base_cfg = hf.ExecConfig(safe_capacities=False)
-if j.lower(base_cfg)().overflow:
-    base_cfg = hf.ExecConfig(safe_capacities=False, shuffle_slack=4.0)
-for tag, cfg in (("baseline", base_cfg),
-                 ("salted", hf.ExecConfig(adaptive_stats=True,
-                                          safe_capacities=False))):
-    plan = j.lower(cfg)
-    t = plan()                  # warmup/compile
-    assert not t.overflow, tag
-    c = np.asarray(t.counts, dtype=np.float64)
-    ts = []
-    for _ in range(5):
-        t0 = time.perf_counter()
-        out = plan()
-        np.asarray(out.counts)
-        ts.append(time.perf_counter() - t0)
-    print("ROW", tag, np.median(ts) * 1e6,
-          c.max() / c.mean(), int(c.max()), int(c.sum()),
-          cfg.shuffle_slack)
-"""
+# Runs on a FIXED 8-device sub-mesh of this process's devices so the skew
+# actually lands on shards regardless of how many the host has; both arms
+# share data, compile cache state and machine noise.  The baseline gets
+# shuffle_slack doubled iff default slack overflows the hot bucket (the
+# steady state the overflow-retry loop reaches on this distribution); the
+# salted arm runs adaptive defaults.
+SKEW_DEVICES = 8
 
 
 def bench_skew_join(n):
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    m = max(64, n // 50)
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(root, "src")
-    env.pop("XLA_FLAGS", None)
-    res = subprocess.run(
-        [sys.executable, "-c",
-         textwrap.dedent(_SKEW_SCRIPT).format(n=n, m=m)],
-        env=env, capture_output=True, text=True, timeout=900)
-    if res.returncode != 0:
-        tail = res.stderr.strip().splitlines()[-1][:80] if res.stderr else "?"
-        report(f"fig13_skew_join_baseline_n{n}", -1.0, f"FAILED:{tail}")
-        report(f"fig13_skew_join_salted_n{n}", -1.0, f"FAILED:{tail}")
+    if jax.device_count() < SKEW_DEVICES:
+        print(f"fig13_skew_join: skipped (<{SKEW_DEVICES} devices)")
         return
+    mesh = Mesh(np.array(jax.devices()[:SKEW_DEVICES]), ("data",))
+    m = max(64, n // 50)
+    rng = np.random.default_rng(13)
+    k = rng.integers(0, m, n).astype(np.int32)
+    k[: int(0.30 * n)] = 3          # one zipf-hot key: ~30% of all probe rows
+    rng.shuffle(k)
+    probe = {"k": k, "v": rng.normal(size=n).astype(np.float32)}
+    dim = {"k": np.arange(m, dtype=np.int32),
+           "w": rng.normal(size=m).astype(np.float32)}
+    j = hf.table(probe, "probe").merge(hf.table(dim, "dim"), on="k")
+
+    base_cfg = hf.ExecConfig(mesh=mesh, safe_capacities=False)
+    if j.lower(base_cfg)().overflow:
+        base_cfg = hf.ExecConfig(mesh=mesh, safe_capacities=False,
+                                 shuffle_slack=4.0)
     rows = {}
-    for line in res.stdout.splitlines():
-        if line.startswith("ROW "):
-            _, tag, us, ratio, cmax, total, slack = line.split()
-            rows[tag] = (float(us), float(ratio), int(cmax), int(total),
-                         float(slack))
-    us_b, r_b, mx_b, _, slack_b = rows["baseline"]
-    us_s, r_s, mx_s, _, _ = rows["salted"]
+    for tag, cfg in (("baseline", base_cfg),
+                     ("salted", hf.ExecConfig(mesh=mesh, adaptive_stats=True,
+                                              safe_capacities=False))):
+        plan = j.lower(cfg)
+        t = plan()                  # warmup/compile
+        if t.overflow:
+            raise RuntimeError(f"fig13 {tag} arm overflowed its buffers")
+        c = np.asarray(t.counts, dtype=np.float64)
+        us = timeit(lambda: np.asarray(plan().counts), warmup=0, repeat=5)
+        rows[tag] = (us, c.max() / c.mean(), int(c.max()), cfg.shuffle_slack)
+    us_b, r_b, mx_b, slack_b = rows["baseline"]
+    us_s, r_s, mx_s, _ = rows["salted"]
     report(f"fig13_skew_join_baseline_n{n}", us_b,
-           f"P=8;occ_max_over_mean={r_b:.2f};max_shard={mx_b};"
+           f"P={SKEW_DEVICES};occ_max_over_mean={r_b:.2f};max_shard={mx_b};"
            f"slack={slack_b:g}")
     report(f"fig13_skew_join_salted_n{n}", us_s,
-           f"P=8;occ_max_over_mean={r_s:.2f};max_shard={mx_s};"
+           f"P={SKEW_DEVICES};occ_max_over_mean={r_s:.2f};max_shard={mx_s};"
            f"speedup={us_b/us_s:.2f}x")
 
 

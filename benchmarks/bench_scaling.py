@@ -1,63 +1,41 @@
-"""Paper Fig. 12 — strong scaling of Q26 (1..8 fake host devices).
+"""Paper Fig. 12 — strong scaling of Q26 over 1, 2, 4 and 8 devices.
 
-Each point runs in a subprocess with a different host-device count (the CPU
-stand-in for nodes).  The paper's point: HiFrames keeps scaling where Spark's
-master bottleneck inverts it; our analogue is that the compiled SPMD plan has
-no coordinator — scaling is bounded only by the collectives.
+Each point runs the same plan in this process on a sub-mesh of the first d
+devices (the stand-in for nodes); device counts the host lacks are
+skipped.  The paper's point: HiFrames keeps scaling where Spark's master
+bottleneck inverts it; our analogue is that the compiled SPMD plan has no
+coordinator — scaling is bounded only by the collectives.
 """
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
-import textwrap
-
-from .common import report
-
-_SCRIPT = """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={devs}"
-import time
-import numpy as np
 import jax
+import numpy as np
+from jax.sharding import Mesh
+
 from repro import hiframes as hf
 from repro.data import synth
 
-ss = synth.store_sales({rows}, 5000, 20000, seed=10)
-it = synth.item(5000, seed=11)
-store_sales, item = hf.table(ss, "ss"), hf.table(it, "it")
-sale_items = hf.join(store_sales, item, on=("ss_item_sk", "i_item_sk"))
-c_i = hf.aggregate(sale_items, "ss_customer_sk",
-                   c_i_count=hf.count(),
-                   id1=hf.sum_(sale_items["i_class_id"] == 1))
-plan = c_i[c_i["c_i_count"] > 2].lower()
-plan()   # warmup/compile
-ts = []
-for _ in range(3):
-    t0 = time.perf_counter()
-    t = plan()
-    np.asarray(t.counts)
-    ts.append(time.perf_counter() - t0)
-print("US_PER_CALL", np.median(ts) * 1e6)
-"""
+from .common import report, timeit
 
 
 def run(scale: float = 1.0, devices=(1, 2, 4, 8)):
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     rows = int(200_000 * scale)
+    ss = synth.store_sales(rows, 5000, 20000, seed=10)
+    it = synth.item(5000, seed=11)
+    store_sales, item = hf.table(ss, "ss"), hf.table(it, "it")
+    sale_items = hf.join(store_sales, item, on=("ss_item_sk", "i_item_sk"))
+    c_i = hf.aggregate(sale_items, "ss_customer_sk",
+                       c_i_count=hf.count(),
+                       id1=hf.sum_(sale_items["i_class_id"] == 1))
+    q26 = c_i[c_i["c_i_count"] > 2]
     base = None
     for d in devices:
-        env = dict(os.environ)
-        env["PYTHONPATH"] = os.path.join(root, "src")
-        env.pop("XLA_FLAGS", None)
-        res = subprocess.run(
-            [sys.executable, "-c", _SCRIPT.format(devs=d, rows=rows)],
-            env=env, capture_output=True, text=True, timeout=900)
-        if res.returncode != 0:
-            report(f"fig12_q26_scaling_p{d}", -1.0,
-                   f"FAILED:{res.stderr.strip().splitlines()[-1][:80] if res.stderr else '?'}")
+        if d > jax.device_count():
+            print(f"fig12_q26_scaling_p{d}: skipped (<{d} devices)")
             continue
-        us = float(res.stdout.split("US_PER_CALL")[1].strip().split()[0])
+        mesh = Mesh(np.array(jax.devices()[:d]), ("data",))
+        plan = q26.lower(hf.ExecConfig(mesh=mesh))
+        us = timeit(lambda: np.asarray(plan().counts), warmup=1, repeat=3)
         if base is None:
             base = us
         report(f"fig12_q26_scaling_p{d}", us,

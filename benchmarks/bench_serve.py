@@ -13,6 +13,7 @@ from __future__ import annotations
 import numpy as np
 
 from repro import hiframes as hf
+from repro.configs.hiframes_tpcx import SF100
 from repro.core.api import ExecConfig
 from repro.launch.serve import build_mix, register_tables
 from repro.runtime.session import Session
@@ -22,11 +23,12 @@ from .common import report, timeit
 
 def run(scale: float = 0.25) -> None:
     with Session(ExecConfig()) as sess:
-        register_tables(sess, scale)
-        mix = build_mix(sess)
+        # scale 1.0 = 120k store_sales rows, a thousandth of SF100
+        register_tables(sess, SF100.scaled(scale * 1e-3))
+        mix = build_mix(sess.table("store_sales"), sess.table("item"))
 
         def one_pass():
-            return [sess.collect(q()) for q in mix]
+            return [sess.collect(q()) for q in mix.values()]
 
         # cold: dedicated cache-empty timing (no timeit warmup — warmup IS
         # the thing being measured), then steady-state through timeit.

@@ -2,7 +2,7 @@
 
 Prints ``name,us_per_call,derived`` CSV.  --scale shrinks/grows datasets
 (defaults are CPU-feasible stand-ins for the paper's cluster sizes);
---skip lets CI drop the slow subprocess scaling runs; --out additionally
+--skip lets CI drop the slow scaling runs; --out additionally
 writes the rows as JSON (the CI bench-smoke artifact).
 """
 import argparse
@@ -21,6 +21,9 @@ def main() -> None:
     ap.add_argument("--out", default=None,
                     help="write results as JSON to this path")
     args = ap.parse_args()
+
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from . import (bench_analytics, bench_kernels, bench_pallas_ab,
                    bench_relational, bench_scaling, bench_serve, bench_tpcx,

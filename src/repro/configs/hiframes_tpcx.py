@@ -37,6 +37,16 @@ SF1000 = TpcxConfig("sf1000", 1_200_000_000, 500_000, 5_000_000,
 Q05_SKEWED = TpcxConfig("q05skew", 120_000_000, 178_000, 990_000,
                         390_000_000, skew=1.1)
 
+# The fractions of SF100 that chip_smoke.py serves on a v5e mesh of 1 or 4
+# chips.  One chip holds all of SF100 (7.5 GiB peak), but Q26 runs for
+# 220 s there and the smoke runs it three times, next to about 11 minutes
+# of compiles: half keeps the smoke inside its 20-minute limit.  On four
+# chips a registered table's shards each hold capacity for every row under
+# safe_capacities, so the exchange buffers grow with P x rows: at half of
+# SF100 the plans asked for more than a chip's 16 GiB; at a tenth (SF10)
+# the leaderboard, the largest, needs about 7.5 GiB per chip (PERF.md).
+V5E_SMOKE_FRACTION = {1: 0.5, 4: 0.1}
+
 # CPU-feasible default used by benchmarks/bench_tpcx.py
 LOCAL = TpcxConfig("local", 400_000, 20_000, 50_000, 400_000, skew=1.1)
 

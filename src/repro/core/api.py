@@ -1055,15 +1055,19 @@ def table(columns: dict[str, Any], name: str = "t") -> DataFrame:
     float columns holding NaN and object columns of numbers with ``None``
     holes become nullable; datetime/complex/structured inputs raise an
     actionable error.  Device (jax) arrays pass through untouched — they are
-    assumed clean, numeric, and possibly mid-computation."""
-    lens = {k: len(v) for k, v in columns.items()}
+    assumed clean, numeric, and possibly mid-computation.  So do
+    ``jax.ShapeDtypeStruct`` columns: such an abstract table plans, lowers
+    and compiles at its size (``Lowered.hlo_text`` / ``_prepare``) but holds
+    no data to execute."""
+    import jax
+    lens = {k: v.shape[0] if isinstance(v, jax.ShapeDtypeStruct) else len(v)
+            for k, v in columns.items()}
     if len(set(lens.values())) > 1:
         raise ValueError(f"column length mismatch: {lens}")
-    import jax
     cols: dict[str, Any] = {}
     sch: dict[str, Any] = {}
     for k, v in columns.items():
-        if isinstance(v, jax.Array):
+        if isinstance(v, (jax.Array, jax.ShapeDtypeStruct)):
             cols[k] = v
             sch[k] = np.dtype(v.dtype)
             continue

@@ -14,7 +14,8 @@ instead of parsing messages:
     return it silently.
   * :class:`KernelBackendError` — a kernel backend (Pallas compiled or
     interpret) failed to build/trace; the degradation ladder steps the ONE
-    offending kernel down (compiled -> interpret -> ref) before giving up.
+    offending kernel down (compiled -> off on a TPU, compiled -> interpret
+    -> off elsewhere) before giving up.
   * :class:`StatsError`         — the adaptive statistics pass failed;
     lowering degrades to static planning and records a degradation event.
 

@@ -23,14 +23,14 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
+    SingleDeviceSharding
 
 from . import distribution as D
 from . import errors as err
 from . import ir, physical as phys
 from . import physical_plan as pp
 from ..kernels import registry as kreg
-from .compat import shard_map as _compat_shard_map
 from .dtypes import NULL_CODE, categories_of, is_category, physical_dtype
 from .expr import ExternalArray, evaluate, nulltag_for
 from .table import DTable, pad_to
@@ -125,7 +125,7 @@ class ExecConfig:
     # compute_capacities — written by runtime.retry.RetryPolicy, not users.
     cap_overrides: Any = None
     # kernel_fallbacks: {kernel name: mode} per-kernel backend overrides —
-    # the degradation-ladder state (compiled -> interpret -> off) driven by
+    # the degradation-ladder state (kernels/registry.downgrade) driven by
     # RetryPolicy on KernelBackendError.  None = all kernels on use_pallas.
     kernel_fallbacks: Any = None
 
@@ -183,10 +183,16 @@ class Lowered:
         need_wrap = (cfg.use_pallas != "off" or bool(fallbacks)
                      or (fault is not None
                          and getattr(fault, "fail_kernel", "")))
+        # kernel name -> (mode, fn, abstract args) of every Pallas kernel the
+        # trace called: a program that fails to compile is blamed on the
+        # first of them that fails to compile alone (_blame_kernel).
+        self.traced_kernels: dict = {}
         self.kernels = kreg.resolve_with(
             cfg.use_pallas, fallbacks,
-            wrap=_kernel_wrap(fault) if need_wrap else None)
+            wrap=(_kernel_wrap(fault, self.traced_kernels)
+                  if need_wrap else None))
         self.mesh = cfg.get_mesh()
+        self.platform = self.mesh.devices.flat[0].platform
         self.P = int(np.prod([self.mesh.shape[a] for a in cfg.axes]))
         self.events: list = []   # degradation events picked up by RetryPolicy
         self.compiles = 0        # jit-cache misses (plan-cache hit => stays 0)
@@ -613,8 +619,18 @@ class Lowered:
 
     # -- public call -----------------------------------------------------------
 
-    def _prepare(self, scan_arrays=None, scan_nodes=None):
+    def _prepare(self, scan_arrays=None, scan_nodes=None,
+                 abstract: bool = False):
         """Marshal inputs and return the (cached) jitted shard_map callable.
+
+        Host inputs are padded and placed by shard: each device receives
+        only its block (``jax.device_put`` with the input's
+        ``NamedSharding``), replicated tables go to every device.  With
+        ``abstract`` — or for columns given as ``jax.ShapeDtypeStruct`` (an
+        abstract table) — host inputs come back as sharded shapes instead,
+        so ``fn.lower(...)`` compiles the plan at that size without moving
+        data: :meth:`compile`, and the compile rehearsal for a chip that is
+        described and not attached.
 
         The jit is cached per source-row signature: rebuilding the closure on
         every call would otherwise retrace+recompile per execution (measured
@@ -627,7 +643,20 @@ class Lowered:
         with the same shard count and capacity, so the shard_map signature
         (and hence the compiled executable) is reused byte-identical.
         """
+        if self.platform == "tpu" and \
+                "interpret" in self.kernels.kernel_modes.values():
+            raise ValueError(
+                "use_pallas='interpret' emulates the Pallas kernels on the "
+                "host and cannot execute on a TPU; use 'compiled' or 'off'")
         mesh, Pn = self.mesh, self.P
+
+        def place(a, spec):
+            sharding = NamedSharding(mesh, spec)
+            if abstract or isinstance(a, jax.ShapeDtypeStruct):
+                return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                            sharding=sharding)
+            return jax.device_put(a, sharding)
+
         inputs = {"scans": {}, "ext": {}, "rows": {}}
         for s in self.scans:
             sub = scan_nodes.get(str(s.id)) if scan_nodes else None
@@ -662,8 +691,8 @@ class Lowered:
                 # negated to stay disjoint from host-scan row counts, so a
                 # same-capacity rebind reuses the compiled executable.
                 inputs["scans"][str(s.id)] = {c: src[c] for c in s.columns}
-                inputs["ext"][_cnt_tag(s.id)] = jnp.asarray(
-                    np.asarray(lay.counts, dtype=np.int32))
+                inputs["ext"][_cnt_tag(s.id)] = place(
+                    np.asarray(lay.counts, dtype=np.int32), P(self.cfg.axes))
                 inputs["rows"][str(s.id)] = -int(lay.capacity) - 1
                 continue
             if sub is not None:
@@ -674,17 +703,19 @@ class Lowered:
                 # host and re-enter as a plain block table (layout claims
                 # were already dropped at planning time).
                 src = lay.gather_host(src)
-            rows = len(next(iter(src.values())))
+            rows = _length(next(iter(src.values())))
             cap = self.pplan.final_op(s).cap
             rep = self.dists[s.id] == D.REP
             n_pad = rows if rep else Pn * cap
+            specs = self._in_specs["scans"][str(s.id)]
             inputs["scans"][str(s.id)] = {
-                c: jnp.asarray(pad_to(np.asarray(v), n_pad)) for c, v in src.items()}
+                c: place(_padded(v, n_pad, abstract), specs[c])
+                for c, v in src.items()}
             inputs["rows"][str(s.id)] = rows
         for tag, arr in self.exts.items():
-            a = np.asarray(arr)
-            cap = self._ext_caps[tag]
-            inputs["ext"][tag] = jnp.asarray(pad_to(a, Pn * cap))
+            inputs["ext"][tag] = place(
+                _padded(arr, Pn * self._ext_caps[tag], abstract),
+                self._in_specs["ext"][tag])
 
         rows_static = dict(inputs["rows"])
         key = tuple(sorted(rows_static.items()))
@@ -695,7 +726,7 @@ class Lowered:
                 return self._per_shard({"scans": scan_cols, "ext": ext_cols,
                                         "rows": rows_static})
 
-            shard_fn = _compat_shard_map(
+            shard_fn = jax.shard_map(
                 wrapped, mesh=mesh,
                 in_specs=(self._in_specs["scans"], self._in_specs["ext"]),
                 out_specs=self._out_specs, check_vma=False)
@@ -703,19 +734,31 @@ class Lowered:
             self.compiles += 1
         return self._jit_cache[key], inputs
 
+    def compile(self, scan_nodes=None):
+        """Compile the plan for its inputs' shapes without moving any data;
+        the next call with those shapes runs the compiled program.  Returns
+        the ``jax.stages.Compiled`` (``as_text()``, ``memory_analysis()``).
+        A serving session compiles here, outside its device lock, so that
+        compiles of concurrent queries overlap."""
+        fn, inputs = self._prepare(scan_nodes=scan_nodes, abstract=True)
+        lowered = self._typed(lambda: fn.lower(inputs["scans"], inputs["ext"]))
+        return self._typed(lowered.compile)
+
     def hlo_text(self, optimized: bool = True) -> str:
         """The (optimized) HLO of the whole plan — used by the UDF-identity
         benchmark (paper Fig. 10) and by EXPLAIN-style tooling."""
-        fn, inputs = self._prepare()
-        lowered = fn.lower(inputs["scans"], inputs["ext"])
-        return lowered.compile().as_text() if optimized else lowered.as_text()
+        if optimized:
+            return self.compile().as_text()
+        fn, inputs = self._prepare(abstract=True)
+        return self._typed(
+            lambda: fn.lower(inputs["scans"], inputs["ext"])).as_text()
 
     def __call__(self, scan_arrays: dict[str, dict[str, np.ndarray]] | None = None,
                  scan_nodes=None):
         """Execute.  scan_arrays overrides source columns by scan id (str);
         scan_nodes rebinds scans to other same-shape tables (plan cache)."""
         fn, inputs = self._prepare(scan_arrays, scan_nodes)
-        out = fn(inputs["scans"], inputs["ext"])
+        out = self._typed(lambda: fn(inputs["scans"], inputs["ext"]))
         cap = self.pplan.root_op.cap
         flags = np.asarray(out["overflow"]).reshape(self.P, -1)
         reqs = np.asarray(out["ovf_req"]).reshape(self.P, -1)
@@ -726,6 +769,49 @@ class Lowered:
                       overflow=bool(flags.any()),
                       overflow_ops=overflow_ops,
                       invariant_failures=failures)
+
+    def _typed(self, step):
+        """Run a lower/compile/execute ``step``.  The TPU compiler refuses a
+        Pallas kernel while the whole program lowers or compiles — after
+        the kernel's own call returned inside ``_kernel_wrap`` — so a
+        failure here is blamed on the first traced kernel that also fails to
+        compile alone and re-raised as a typed KernelBackendError (which
+        the retry ladder steps down).  Anything else propagates as is."""
+        try:
+            return step()
+        except err.HiFramesError:
+            raise
+        except Exception as e:
+            blame = self._blame_kernel()
+            if blame is None:
+                raise
+            name, mode, cause = blame
+            raise err.KernelBackendError(name, mode, cause) from e
+
+    def _blame_kernel(self):
+        """``(name, mode, error)`` of the first traced kernel whose program
+        alone fails to lower or compile on this mesh's device; None when
+        every one compiles (the failure lies elsewhere)."""
+        device = SingleDeviceSharding(self.mesh.devices.flat[0])
+        for name, (mode, fn, args) in list(self.traced_kernels.items()):
+            leaves, tree = jax.tree.flatten(args)
+            slots = [i for i, x in enumerate(leaves)
+                     if isinstance(x, jax.ShapeDtypeStruct)]
+
+            def alone(*arrays, leaves=leaves, tree=tree, slots=slots, fn=fn):
+                full = list(leaves)
+                for i, a in zip(slots, arrays):
+                    full[i] = a
+                a, k = jax.tree.unflatten(tree, full)
+                return fn(*a, **k)
+
+            shapes = [jax.ShapeDtypeStruct(leaves[i].shape, leaves[i].dtype,
+                                           sharding=device) for i in slots]
+            try:
+                jax.jit(alone).lower(*shapes).compile()
+            except Exception as e:   # any refusal of the kernel's program
+                return name, mode, e
+        return None
 
     def _attribute_overflow(self, flags: np.ndarray,
                             reqs: np.ndarray) -> dict[int, dict]:
@@ -813,9 +899,12 @@ def _capacity_sites(pplan: pp.PhysicalPlan) -> list[tuple[int, str, str, str]]:
     return sites
 
 
-def _kernel_wrap(fault):
-    """Registry ``wrap`` hook: type real kernel-backend failures as
-    KernelBackendError and honor FaultPlan.fail_kernel injection."""
+def _kernel_wrap(fault, traced: dict):
+    """Registry ``wrap`` hook: type kernel-backend failures raised while
+    tracing as KernelBackendError, honor FaultPlan.fail_kernel injection, and
+    record each Pallas kernel's abstract call in ``traced`` (name -> (mode,
+    fn, args)) so that a compile failure of the whole program can be blamed
+    on the kernel that causes it (``Lowered._typed``)."""
     def wrap(name, mode, fn):
         injected = fault is not None and fault.kernel_fails(name, mode)
         if mode == "off" and not injected:
@@ -824,6 +913,8 @@ def _kernel_wrap(fault):
             if injected:
                 raise err.KernelBackendError(
                     name, mode, "injected fault (FaultPlan.fail_kernel)")
+            if mode != "off":
+                traced.setdefault(name, (mode, fn, _abstract((a, k))))
             try:
                 return fn(*a, **k)
             except err.HiFramesError:
@@ -832,6 +923,27 @@ def _kernel_wrap(fault):
                 raise err.KernelBackendError(name, mode, e) from e
         return call
     return wrap
+
+
+def _abstract(tree):
+    """Arrays (and tracers) -> ShapeDtypeStruct; static leaves unchanged."""
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
+        if isinstance(x, (jax.Array, np.ndarray)) else x, tree)
+
+
+def _length(col) -> int:
+    return col.shape[0] if hasattr(col, "shape") else len(col)
+
+
+def _padded(col, n: int, abstract: bool = False):
+    """A host column zero-padded to ``n`` rows or, for an abstract column
+    or with ``abstract``, the shape it would have on the device."""
+    if abstract or isinstance(col, jax.ShapeDtypeStruct):
+        a = col if hasattr(col, "dtype") else np.asarray(col)
+        return jax.ShapeDtypeStruct((n,) + tuple(a.shape[1:]),
+                                    jax.dtypes.canonicalize_dtype(a.dtype))
+    return pad_to(np.asarray(col), n)
 
 
 def _checksum_u32(cols: dict, cnt) -> jax.Array:

@@ -45,10 +45,7 @@ def _K(kernels) -> "_registry.KernelSet":
 # ---------------------------------------------------------------------------
 
 def nshards(axes: Axes) -> int:
-    if hasattr(lax, "axis_size"):                 # jax >= 0.6
-        return int(np.prod([lax.axis_size(a) for a in axes]))
-    return int(lax.psum(1, tuple(axes)))          # 0.4.x: psum of a python int
-                                                  # is constant-folded -> static
+    return int(np.prod([lax.axis_size(a) for a in axes]))
 
 
 def my_rank(axes: Axes):
@@ -583,8 +580,8 @@ def segment_aggregate(keys_sorted, count, values: dict[str, tuple],
             x = x.astype(jnp.int32)      # sum(:x < 1.0) counts True rows
         if jnp.issubdtype(x.dtype, jnp.floating):
             # registry segment_sums: ref is the dtype-preserving
-            # jax.ops.segment_sum composition; the Pallas backend is the
-            # segment_reduce scan-difference kernel (f32 accumulation).
+            # jax.ops.segment_sum composition; the Pallas backend reads
+            # each run's total off a per-run f32 scan (segment_reduce).
             return _K(kernels).segment_sums(x, seg_id, v, cap_out)
         # integer sums stay on segment_sum directly for exactness (the
         # Pallas kernel accumulates in f32).

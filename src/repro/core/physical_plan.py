@@ -1051,7 +1051,8 @@ def scan_rows(n: ir.Scan) -> int:
     prefixes (the columns are padded ``(nshards * capacity,)`` buffers)."""
     if n.layout is not None and n.layout.counts is not None:
         return n.layout.rows()
-    return len(next(iter(n.columns.values())))
+    col = next(iter(n.columns.values()))
+    return col.shape[0] if hasattr(col, "shape") else len(col)
 
 
 def compute_capacities(plan: PhysicalPlan, P: int, cfg,

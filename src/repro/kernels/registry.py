@@ -84,7 +84,7 @@ class KernelSet:
 
     ``overrides`` maps kernel name -> mode, stepping INDIVIDUAL kernels off
     the global mode — the carrier of the per-kernel degradation ladder
-    (compiled -> interpret -> off) the retry policy drives on
+    (:func:`downgrade`) the retry policy drives on
     :class:`~repro.core.errors.KernelBackendError`.  ``wrap`` is an optional
     ``wrap(name, mode, fn) -> fn`` hook applied to every resolved callable
     (error typing + fault injection, core/lower.py).
@@ -146,8 +146,18 @@ def resolve_with(mode: str, overrides: dict | None = None,
     return KernelSet(mode, overrides, wrap)
 
 
-DOWNGRADE = {"compiled": "interpret", "interpret": "off", "off": None}
-"""The degradation ladder: next-softer backend per mode (None = exhausted)."""
+def downgrade(mode: str, platform: str) -> str | None:
+    """The degradation ladder: the next-softer backend for a kernel that
+    failed under ``mode`` on a ``platform`` device (None = exhausted).  On a
+    TPU the interpreter is no rung — it would emulate the kernel on the host
+    at a fraction of the speed — so ``compiled`` falls straight to ``off``;
+    elsewhere ``compiled`` (which only a TPU can run) steps to
+    ``interpret``."""
+    if mode == "compiled":
+        return "off" if platform == "tpu" else "interpret"
+    if mode == "interpret":
+        return "off"
+    return None
 
 
 # -- registrations -------------------------------------------------------------

@@ -10,12 +10,12 @@ scans of those masks:
   rank[i]       = segmented running max of (ord_b ? row_number : 0)
                   (row_number at the latest run head — ties share it)
 
-The kernel runs the same Hillis-Steele segmented-scan ladder as
-``segment_scan`` (sum monoid for the count, max monoid with identity 0 for
-rank), with a two-cell VMEM carry: cell 0 holds the count scan at the
-previous block's last row, cell 1 the running max.  The max carry is valid
-across blocks because row_number only grows within a segment and the latest
-run head at or before row i is always inside row i's segment.
+The kernel runs the segmented rotation ladder of ``kernels/tiling.py`` (sum
+monoid for the count, max monoid with identity 0 for rank), with two
+``(1, 128)`` VMEM carries: the count scan at the previous block's last row
+and the running max.  The max carry is valid across blocks because
+row_number only grows within a segment and the latest run head at or before
+row i is always inside row i's segment.
 """
 from __future__ import annotations
 
@@ -23,42 +23,20 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-BLOCK = 2048
+from ..tiling import scan_block, scan_call
 
 
-def _seg_ladder(v, f, combine):
-    shift = 1
-    while shift < BLOCK:
-        vs = jnp.concatenate([jnp.zeros((shift,), v.dtype), v[:-shift]])
-        fs = jnp.concatenate([jnp.zeros((shift,), jnp.bool_), f[:-shift]])
-        v = combine(v, jnp.where(f, jnp.zeros((), v.dtype), vs))
-        f = f | fs
-        shift *= 2
-    return v, f
-
-
-def _kernel(seg_ref, ord_ref, o_ref, carry, *, kind: str):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        carry[0] = jnp.zeros((), jnp.int32)
-        carry[1] = jnp.zeros((), jnp.int32)
-
-    f = seg_ref[...] != 0
-    ob = ord_ref[...] != 0
-    inc = ob.astype(jnp.int32) if kind == "dense_rank" \
-        else jnp.ones((BLOCK,), jnp.int32)
-    v, ff = _seg_ladder(inc, f, jnp.add)
-    rn = v + jnp.where(ff, 0, carry[0])
-    carry[0] = rn[-1]
+def _kernel(seg_ref, ord_ref, o_ref, c_count, c_max, *, kind: str):
+    f = seg_ref[...]
+    ob = ord_ref[...]
+    inc = ob if kind == "dense_rank" else jnp.ones_like(ob)
+    rn, nxt = scan_block(inc, c_count[...], flags=f)
+    c_count[...] = nxt
     if kind == "rank":
-        m, fm = _seg_ladder(jnp.where(ob, rn, 0), f, jnp.maximum)
-        out = jnp.maximum(m, jnp.where(fm, 0, carry[1]))
-        carry[1] = out[-1]
+        out, nxt = scan_block(jnp.where(ob != 0, rn, 0), c_max[...],
+                              flags=f, op=jnp.maximum)
+        c_max[...] = nxt
         o_ref[...] = out
     else:
         o_ref[...] = rn
@@ -67,19 +45,9 @@ def _kernel(seg_ref, ord_ref, o_ref, carry, *, kind: str):
 def segment_rank_pallas(seg_b: jax.Array, ord_b: jax.Array, kind: str,
                         interpret: bool = True) -> jax.Array:
     """1-based in-segment ranks; kind in {row_number, rank, dense_rank}."""
-    n = seg_b.shape[0]
-    nb = max(1, -(-n // BLOCK))
-    pad = (0, nb * BLOCK - n)
-    sp = jnp.pad(seg_b.astype(jnp.int32), pad, constant_values=1)
-    op = jnp.pad(ord_b.astype(jnp.int32), pad, constant_values=1)
-    out = pl.pallas_call(
-        functools.partial(_kernel, kind=kind),
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((BLOCK,), lambda i: (i,)),
-                  pl.BlockSpec((BLOCK,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((BLOCK,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((nb * BLOCK,), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((2,), jnp.int32)],
-        interpret=interpret,
-    )(sp, op)
-    return out[:n]
+    if kind not in ("row_number", "rank", "dense_rank"):
+        raise ValueError(f"unknown rank kind: {kind!r}")
+    return scan_call(functools.partial(_kernel, kind=kind),
+                     [(seg_b != 0).astype(jnp.int32),
+                      (ord_b != 0).astype(jnp.int32)],
+                     [1, 1], jnp.int32, [jnp.int32, jnp.int32], interpret)

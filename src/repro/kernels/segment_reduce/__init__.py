@@ -1,2 +1,2 @@
 from . import ops, ref
-from .segment_reduce import value_scan_pallas
+from .segment_reduce import run_scan_pallas

@@ -1,10 +1,10 @@
-"""jit'd wrapper: per-segment sums via scan-difference at run boundaries."""
+"""jit'd wrapper: per-segment sums read off a segmented scan at run ends."""
 import functools
 
 import jax
 import jax.numpy as jnp
 
-from .segment_reduce import value_scan_pallas
+from .segment_reduce import run_scan_pallas
 
 
 @functools.partial(jax.jit, static_argnames=("num_segments", "interpret"))
@@ -14,17 +14,13 @@ def segment_sums(values, seg_id, valid, num_segments: int, interpret: bool = Tru
     values: (n,), seg_id: (n,) int32 sorted ascending over the valid prefix.
     Returns (num_segments,) f32 sums (empty segments -> 0).
     """
-    n = values.shape[0]
     v = jnp.where(valid, values.astype(jnp.float32), 0.0)
-    s = value_scan_pallas(v, interpret=interpret)            # kernel phase
+    prv = jnp.concatenate([jnp.full((1,), -1, seg_id.dtype), seg_id[:-1]])
+    s = run_scan_pallas(v, seg_id != prv, interpret=interpret)  # kernel phase
     nxt = jnp.concatenate([seg_id[1:], jnp.full((1,), -1, seg_id.dtype)])
     nxt_valid = jnp.concatenate([valid[1:], jnp.zeros((1,), bool)])
     is_end = valid & ((seg_id != nxt) | ~nxt_valid)
     sid = jnp.where(is_end, seg_id, num_segments)
-    # E[k] = scan value at the end of segment k
-    sE = jnp.zeros((num_segments + 1,), jnp.float32).at[sid].set(s, mode="drop")
-    sE = sE[:num_segments]
-    prev = jnp.concatenate([jnp.zeros((1,), jnp.float32), sE[:-1]])
-    # empty segments cannot occur by construction (consecutive ids), so the
-    # running difference recovers exact segment totals.
-    return sE - prev
+    # the scan value at the end of segment k is segment k's total
+    return jnp.zeros((num_segments + 1,), jnp.float32).at[sid].set(
+        s, mode="drop")[:num_segments]

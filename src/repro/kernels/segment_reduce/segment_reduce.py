@@ -1,47 +1,25 @@
-"""Pallas kernel: segmented reduction over sorted runs (aggregate backend).
+"""Pallas kernel phase of the segmented reduction over sorted runs.
 
 The TPU replacement for the paper's hash-table aggregation: after the shuffle
-and local sort, rows with equal keys are contiguous runs.  The kernel computes
-a carried inclusive prefix-sum of the values (float32 accumulation); the
-wrapper then derives every run's sum as the difference of the scan at run
-boundaries — one sequential pass over HBM-streamed blocks, no scatter in the
-inner loop (scatters are the VPU's weakness; boundary gathers are tiny).
+and local sort, rows with equal keys are contiguous runs.  The kernel phase is
+a float32 scan that restarts at every run start (the ``segment_scan``
+kernel); the wrapper reads each run's total off the scan at the run's last
+row — one sequential pass over HBM-streamed blocks, no scatter in the inner
+loop (scatters are the VPU's weakness; the end-of-run scatter is tiny).
+Restarting per run keeps every sum as accurate as the run is short: a
+difference of one whole-shard prefix sum would lose the run's low-order bits
+to the magnitude of everything before it.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-BLOCK = 2048
+from ..segment_scan.segment_scan import segment_scan_pallas
 
 
-def _scan_kernel(v_ref, o_ref, carry):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        carry[0] = jnp.zeros((), jnp.float32)
-
-    v = v_ref[...].astype(jnp.float32)
-    c = jnp.cumsum(v)
-    o_ref[...] = c + carry[0]
-    carry[0] = carry[0] + c[-1]
-
-
-def value_scan_pallas(values: jax.Array, interpret: bool = True) -> jax.Array:
-    """Inclusive f32 prefix sum of values (the kernel phase)."""
-    n = values.shape[0]
-    nb = max(1, -(-n // BLOCK))
-    vp = jnp.pad(values.astype(jnp.float32), (0, nb * BLOCK - n))
-    out = pl.pallas_call(
-        _scan_kernel,
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((BLOCK,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((BLOCK,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((nb * BLOCK,), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((1,), jnp.float32)],
-        interpret=interpret,
-    )(vp)
-    return out[:n]
+def run_scan_pallas(values: jax.Array, starts: jax.Array,
+                    interpret: bool = True) -> jax.Array:
+    """Inclusive f32 scan of values, restarting where ``starts`` is set."""
+    return segment_scan_pallas(values.astype(jnp.float32), starts,
+                               interpret=interpret)

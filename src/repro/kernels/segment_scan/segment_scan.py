@@ -8,58 +8,28 @@ base.  This kernel fuses them into ONE pass using the segmented-scan monoid
 
     (v1, f1) + (v2, f2) = (v2 if f2 else v1 + v2,  f1 | f2)
 
-applied as an in-block Hillis-Steele ladder (log2(BLOCK) static shifted adds,
-pure VPU, no gathers), with a single-element VMEM cell carrying the segmented
-scan value at the previous block's last row.  Rows before the first in-block
-boundary continue the prior segment, so adding the carry where the
-accumulated flag is still unset is exactly the cross-block fixup.
+applied as the rotation ladder of ``kernels/tiling.py`` (pure VPU, no
+gathers), with a ``(1, 128)`` VMEM tile carrying the scan value at the
+previous block's last row.  Rows before the first in-block boundary continue
+the prior segment, so adding the carry where the accumulated flag is still
+unset is exactly the cross-block fixup.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-BLOCK = 2048
+from ..tiling import scan_block, scan_call
 
 
 def _kernel(x_ref, b_ref, o_ref, carry):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        carry[0] = jnp.zeros((), x_ref.dtype)
-
-    v = x_ref[...]
-    f = b_ref[...] != 0
-    shift = 1
-    while shift < BLOCK:                      # static ladder: log2(BLOCK) steps
-        vs = jnp.concatenate([jnp.zeros((shift,), v.dtype), v[:-shift]])
-        fs = jnp.concatenate([jnp.zeros((shift,), jnp.bool_), f[:-shift]])
-        v = v + jnp.where(f, jnp.zeros((), v.dtype), vs)
-        f = f | fs
-        shift *= 2
-    out = v + jnp.where(f, jnp.zeros((), v.dtype), carry[0])
+    out, nxt = scan_block(x_ref[...], carry[...], flags=b_ref[...])
     o_ref[...] = out
-    carry[0] = out[-1]
+    carry[...] = nxt
 
 
 def segment_scan_pallas(x: jax.Array, boundary: jax.Array,
                         interpret: bool = True) -> jax.Array:
     """Segmented inclusive sum-scan; boundary != 0 starts a new segment."""
-    n = x.shape[0]
-    nb = max(1, -(-n // BLOCK))
-    xp = jnp.pad(x, (0, nb * BLOCK - n))
-    bp = jnp.pad(boundary.astype(jnp.int32), (0, nb * BLOCK - n))
-    out = pl.pallas_call(
-        _kernel,
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((BLOCK,), lambda i: (i,)),
-                  pl.BlockSpec((BLOCK,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((BLOCK,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((nb * BLOCK,), x.dtype),
-        scratch_shapes=[pltpu.VMEM((1,), x.dtype)],
-        interpret=interpret,
-    )(xp, bp)
-    return out[:n]
+    b = (boundary != 0).astype(jnp.int32)
+    return scan_call(_kernel, [x, b], [0, 0], x.dtype, [x.dtype], interpret)

@@ -3,9 +3,10 @@
 Filter is the paper's no-communication operator: each shard moves its kept
 rows into a dense prefix.  The hot loop is the inclusive prefix-sum of the
 keep-predicate that assigns destination slots.  TPU grid steps execute
-sequentially, so a single-element VMEM scratch carries the running total
-across blocks — one pass, no re-scan (the classic decoupled-lookback is
-unnecessary on TPU's sequential grid).
+sequentially, so a ``(1, 128)`` VMEM tile carries the running total across
+lane-dense blocks — one pass, no re-scan (the classic decoupled-lookback is
+unnecessary on TPU's sequential grid).  The in-block scan is the rotation
+ladder of ``kernels/tiling.py``.
 
 The same kernel (float path) is the local phase of distributed cumsum
 (paper Fig. 8b) — MPI_Exscan's local partial sums.
@@ -13,38 +14,16 @@ The same kernel (float path) is the local phase of distributed cumsum
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-BLOCK = 2048
+from ..tiling import scan_block, scan_call
 
 
 def _kernel(x_ref, o_ref, carry):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        carry[0] = jnp.zeros((), x_ref.dtype)
-
-    x = x_ref[...]
-    c = jnp.cumsum(x)
-    o_ref[...] = c + carry[0]
-    carry[0] = carry[0] + c[-1]
+    out, nxt = scan_block(x_ref[...], carry[...])
+    o_ref[...] = out
+    carry[...] = nxt
 
 
 def prefix_sum_pallas(x: jax.Array, interpret: bool = True) -> jax.Array:
     """Inclusive prefix sum over a 1-D array (int32/float32)."""
-    n = x.shape[0]
-    nb = max(1, -(-n // BLOCK))
-    xp = jnp.pad(x, (0, nb * BLOCK - n))
-    out = pl.pallas_call(
-        _kernel,
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((BLOCK,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((BLOCK,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((nb * BLOCK,), x.dtype),
-        scratch_shapes=[pltpu.VMEM((1,), x.dtype)],
-        interpret=interpret,
-    )(xp)
-    return out[:n]
+    return scan_call(_kernel, [x], [0], x.dtype, [x.dtype], interpret)

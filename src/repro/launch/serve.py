@@ -13,10 +13,11 @@ session's plan cache.  Two modes:
     fewer collectives than the first (pass 1 pays registration).  Exits
     nonzero on violation.
 
-Run on N fake devices:
+Tables are sized from the paper's SF100 row counts
+(``configs/hiframes_tpcx.py``) times ``--scale``.  Run on N fake devices:
 
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
-        PYTHONPATH=src python -m repro.launch.serve --smoke
+        PYTHONPATH=src python -m repro.launch.serve --smoke --scale 5e-5
 """
 from __future__ import annotations
 
@@ -25,16 +26,18 @@ import sys
 import tempfile
 
 from repro import hiframes as hf
+from repro.configs.hiframes_tpcx import SF100, TpcxConfig
 from repro.core.api import DataFrame, ExecConfig
 from repro.data import synth
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.session import Session
 
 
-def build_mix(sess: Session) -> list:
-    """The replayed query mix: Q26 (join + aggregate + filter), a grouped
-    top-up aggregate, and a global leaderboard rank — three distinct plan
-    shapes exercising join, aggregation, and the global-window path."""
-    ss, it = sess.table("store_sales"), sess.table("item")
+def build_mix(ss: DataFrame, it: DataFrame) -> dict:
+    """The replayed query mix over ``store_sales`` and ``item``, by name:
+    Q26 (join + aggregate + filter), a grouped top-up aggregate, and a
+    global leaderboard rank — three distinct plan shapes exercising join,
+    aggregation, and the global-window path."""
 
     def q26() -> DataFrame:
         j = ss.merge(it, on=("ss_item_sk", "i_item_sk"))
@@ -52,24 +55,28 @@ def build_mix(sess: Session) -> list:
         per = ss.groupby("ss_customer_sk").agg(spend=("ss_net_paid", "sum"))
         return hf.rank(per, [], ["spend"], out="r", ascending=False)
 
-    return [q26, per_item, leaderboard]
+    return {"q26": q26, "per_item": per_item, "leaderboard": leaderboard}
 
 
-def register_tables(sess: Session, scale: float, seed: int = 0) -> None:
-    n_sales = max(int(200_000 * scale), 2_000)
-    n_items = max(int(2_000 * scale), 64)
-    n_cust = max(int(10_000 * scale), 128)
-    ss = synth.store_sales(n_sales, n_items, n_cust, seed=seed)
-    it = synth.item(n_items, seed=seed + 1)
+def register_tables(sess: Session, tcfg: TpcxConfig,
+                    seed: int = 0) -> tuple[dict, dict]:
+    """Register ``store_sales`` (hash-partitioned on the join key) and a
+    replicated ``item`` at the row counts of ``tcfg``; returns the two host
+    tables (column dicts) they were made from."""
+    ss = synth.store_sales(tcfg.store_sales_rows, tcfg.items, tcfg.customers,
+                           seed=seed, skew=tcfg.skew)
+    it = synth.item(tcfg.items, seed=seed + 1)
     sess.register("store_sales", hf.table(ss, "store_sales"),
                   partition_by="ss_item_sk")
     sess.register("item", hf.table(it, "item").replicate())
+    return ss, it
 
 
 def run_pass(sess: Session, mix, repeats: int = 2) -> dict:
     """Submit the whole mix (each query ``repeats`` times) through the
     session's concurrent admission and collect per-pass totals."""
-    futures = [sess.submit(q()) for _ in range(repeats) for q in mix]
+    futures = [sess.submit(q()) for _ in range(repeats)
+               for q in mix.values()]
     recs = [f.result().query_record for f in futures]
     return {"queries": len(recs),
             "hits": sum(r.cache == "hit" for r in recs),
@@ -81,8 +88,8 @@ def run_pass(sess: Session, mix, repeats: int = 2) -> dict:
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("--scale", type=float, default=0.05,
-                    help="synthetic data scale factor")
+    ap.add_argument("--scale", type=float, default=1e-3,
+                    help="fraction of the SF100 row counts")
     ap.add_argument("--repeats", type=int, default=2,
                     help="times each mix query runs per pass")
     ap.add_argument("--session-dir", default=None,
@@ -92,11 +99,12 @@ def main(argv=None) -> int:
                          " zero compiles, strictly fewer collectives")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     sdir = args.session_dir or tempfile.mkdtemp(prefix="hf-serve-")
     cfg = ExecConfig()
     with Session(cfg, session_dir=sdir) as sess:
-        register_tables(sess, args.scale)
-        mix = build_mix(sess)
+        register_tables(sess, SF100.scaled(args.scale))
+        mix = build_mix(sess.table("store_sales"), sess.table("item"))
         p1 = run_pass(sess, mix, args.repeats)
         p1_total_coll = p1["collectives"] + sess.stats()[
             "register_collectives"]

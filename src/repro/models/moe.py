@@ -19,7 +19,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ..core.compat import shard_map as _compat_shard_map
 from .config import ModelConfig
 from .layers import swiglu
 
@@ -178,7 +177,7 @@ def _moe_block_ep(p: dict, x, cfg: ModelConfig, mesh):
 
     x_spec = P(dp if dp else None, None, None)
     e_spec = jax.tree.map(lambda _: P("model", None, None), p["experts"])
-    y, aux = _compat_shard_map(
+    y, aux = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(x_spec, P(), e_spec),
         out_specs=(x_spec, P()), check_vma=False,

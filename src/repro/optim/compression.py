@@ -36,12 +36,9 @@ def compressed_psum(g, err, axis_names):
     negligible traffic) BEFORE quantizing, so the summed int8 payload
     dequantizes exactly.
     """
-    if hasattr(lax, "axis_size"):                 # jax >= 0.6
-        P = 1
-        for a in axis_names:
-            P *= lax.axis_size(a)
-    else:                                         # 0.4.x: constant-folded psum
-        P = lax.psum(1, tuple(axis_names))
+    P = 1
+    for a in axis_names:
+        P *= lax.axis_size(a)
     corrected = g.astype(jnp.float32) + err
     amax = jnp.max(jnp.abs(corrected))
     scale = jnp.maximum(lax.pmax(amax, axis_names) / 127.0, 1e-12)

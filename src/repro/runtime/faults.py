@@ -12,7 +12,7 @@ failure handling works instead of waiting for real skew/backend bugs:
     real overflow takes.
   * ``fail_kernel`` — raise :class:`~repro.core.errors.KernelBackendError`
     when the named kernel is resolved on one of ``fail_modes``; the
-    degradation ladder steps that kernel down (compiled -> interpret -> ref)
+    degradation ladder steps that kernel down (``kernels/registry.downgrade``)
     and the query still answers.
   * ``corrupt_exchange`` — flip a value in the first output column of
     matching exchanges (row 0, valid rows only): the model of a packed-payload

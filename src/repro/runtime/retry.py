@@ -17,8 +17,9 @@ One :class:`RetryPolicy` drives every re-execution decision in the engine
     four capacity knobs (join_expansion, shuffle_slack, stats_cap_slack,
     agg_group_cap) and replan.
   * **Degradation ladder** — never a crash when a softer mode exists:
-    ``KernelBackendError`` steps ONE kernel down compiled -> interpret -> off
-    (kernels/registry.DOWNGRADE, carried in ``ExecConfig.kernel_fallbacks``);
+    ``KernelBackendError`` steps ONE kernel down compiled -> off on a TPU,
+    compiled -> interpret -> off elsewhere (``kernels/registry.downgrade``,
+    carried in ``ExecConfig.kernel_fallbacks``);
     a packed-exchange checksum/rowcount invariant failure falls back to the
     unpacked per-column exchange; a stats failure already degraded
     adaptive -> static inside ``lower()`` and surfaces here as an event.
@@ -215,7 +216,8 @@ class RetryPolicy:
         """One rung down for the failing kernel; None when exhausted."""
         from ..kernels import registry as kreg
         fallbacks = dict(getattr(cfg, "kernel_fallbacks", None) or {})
-        nxt = kreg.DOWNGRADE.get(e.backend)
+        platform = cfg.get_mesh().devices.flat[0].platform
+        nxt = kreg.downgrade(e.backend, platform)
         if nxt is None:
             return None
         fallbacks[e.kernel] = nxt

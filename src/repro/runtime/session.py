@@ -92,7 +92,7 @@ class QueryRecord:
     qid: int
     fingerprint: str
     cache: str = "miss"             # "hit" | "miss" | "hit_fallback"
-    plan_s: float = 0.0             # host-side planning + lowering
+    plan_s: float = 0.0             # host-side planning, lowering, compile
     exec_s: float = 0.0             # device execution (mesh lock held)
     collectives: int = 0            # plan's all_to_all count per execution
     compiles: int = 0               # NEW jit entries this query caused
@@ -137,6 +137,11 @@ class PlanCache:
 
     def __len__(self) -> int:
         return len(self._d)
+
+    def plans(self) -> list[Lowered]:
+        """The cached compiled plans, least recently used first."""
+        with self._lock:
+            return [e.lowered for e in self._d.values()]
 
 
 def _topo_scans(node: ir.Node) -> list[ir.Scan]:
@@ -259,12 +264,10 @@ class Session:
             out = df.persist(self.cfg, name=name)
         # registration cost (collectives) is charged to the session, not to
         # the steady-state query mix (the serve smoke's pass-1 total): a
-        # host-only re-lower of the same plan yields the collective count.
-        try:
-            low, _ = lower(df.node, self.cfg, force_rep=df._force_rep())
-            self._register_collectives += low.pplan.collective_count()
-        except Exception:
-            pass
+        # host-only re-lower of the plan persist() just ran yields the
+        # collective count (it cannot fail where that lowering succeeded).
+        low, _ = lower(df.node, self.cfg, force_rep=df._force_rep())
+        self._register_collectives += low.pplan.collective_count()
         return out
 
     def table(self, name: str) -> DataFrame:
@@ -369,10 +372,12 @@ class Session:
         timings = {"plan": 0.0, "exec": 0.0}
 
         def run_once(c):
-            # lowering (host-side) runs outside the mesh lock so other
-            # queries' planning overlaps; execution serializes.
+            # lowering and compiling (host-side) run outside the mesh lock
+            # so other queries' planning and compiles overlap; execution
+            # serializes.
             ta = _MONO()
             lowered, _ = lower(df.node, c, force_rep=df._force_rep())
+            lowered.compile()
             tb = _MONO()
             timings["plan"] += tb - ta
             with self._mesh_lock:

@@ -159,10 +159,9 @@ def test_gradient_compression_8dev():
         g_local = np.stack([np.full((64,), i, np.float32) for i in range(8)])
         def f(g, e):
             return compression.compressed_psum(g, e, ("data",))
-        from repro.core.compat import shard_map
-        out, err = jax.jit(shard_map(
+        out, err = jax.jit(jax.shard_map(
             f, mesh=mesh, in_specs=(P("data"), P("data")),
-            out_specs=(P("data"), P("data"))))(
+            out_specs=(P("data"), P("data")), check_vma=False))(
             jnp.asarray(g_local.reshape(-1)),
             jnp.zeros((8*64,), jnp.float32))
         got = np.asarray(out).reshape(8, 64)
@@ -219,7 +218,7 @@ def test_small_mesh_model_lowering():
         state = {"params": params, "opt": opt}
         toks = jnp.zeros((8, 32), jnp.int32)
         batch = {"tokens": toks, "labels": toks}
-        with jax.sharding.use_mesh(mesh) if hasattr(jax.sharding, "use_mesh") else mesh:
+        with mesh:
             st2, loss = jax.jit(fn)(state, batch)
         assert np.isfinite(float(loss))
     """)

@@ -352,6 +352,18 @@ def test_env_default(monkeypatch):
     assert hf.ExecConfig().use_pallas == "off"
 
 
+@pytest.mark.parametrize("mode,platform,nxt", [
+    ("compiled", "tpu", "off"),          # no interpreter rung on a TPU
+    ("compiled", "cpu", "interpret"),
+    ("interpret", "cpu", "off"),
+    ("interpret", "tpu", "off"),
+    ("off", "tpu", None),
+    ("off", "cpu", None),
+])
+def test_downgrade_ladder(mode, platform, nxt):
+    assert kreg.downgrade(mode, platform) == nxt
+
+
 def test_registry_shape():
     ks = kreg.resolve("interpret")
     assert "KernelSet" in repr(ks)

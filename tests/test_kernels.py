@@ -17,7 +17,7 @@ RNG = np.random.default_rng(11)
 
 
 @pytest.mark.parametrize("n", [1, 7, 100, 2048, 5000])
-@pytest.mark.parametrize("K", [1, 3, 5, 7])
+@pytest.mark.parametrize("K", [1, 3, 5, 7, 200])
 def test_stencil_shapes(n, K):
     ext = RNG.normal(size=n + K - 1).astype(np.float32)
     w = RNG.normal(size=K).tolist()
@@ -158,7 +158,7 @@ def test_segment_rank_vs_loop(kind, n):
 
 
 @pytest.mark.parametrize("n,K,center", [(50, 3, 1), (500, 5, 4), (2048, 4, 0),
-                                        (3000, 7, 3)])
+                                        (3000, 7, 3), (3000, 200, 100)])
 def test_stencil1d_exact_vs_loop(n, K, center):
     rng = np.random.default_rng(n + K)
     w = rng.random(K).astype(np.float32) + 0.1

@@ -481,7 +481,7 @@ def test_serve_smoke_cli():
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
     res = subprocess.run(
         [sys.executable, "-m", "repro.launch.serve", "--smoke",
-         "--scale", "0.01", "--repeats", "1"],
+         "--scale", "2e-5", "--repeats", "1"],
         env=env, capture_output=True, text=True, timeout=600)
     assert res.returncode == 0, \
         f"stdout:\n{res.stdout[-2000:]}\nstderr:\n{res.stderr[-2000:]}"

@@ -4,8 +4,7 @@ Scale factors follow the paper's evaluation section: the micro-benchmarks
 use uniform tables (filter 2B rows / join 0.5M / aggregate 256M at paper
 scale), Q05/Q25/Q26 use BigBench-like tables; Q05 adds the Zipf skew that
 drives the paper's skew/OOM discussion.  ``scaled(sf)`` maps a TPCx-BB-ish
-scale factor to row counts; the benchmark harness defaults to CPU-feasible
-fractions of these.
+scale factor to row counts.
 """
 from __future__ import annotations
 
@@ -46,15 +45,3 @@ Q05_SKEWED = TpcxConfig("q05skew", 120_000_000, 178_000, 990_000,
 # SF100 the plans asked for more than a chip's 16 GiB; at a tenth (SF10)
 # the leaderboard, the largest, needs about 7.5 GiB per chip (PERF.md).
 V5E_SMOKE_FRACTION = {1: 0.5, 4: 0.1}
-
-# CPU-feasible default used by benchmarks/bench_tpcx.py
-LOCAL = TpcxConfig("local", 400_000, 20_000, 50_000, 400_000, skew=1.1)
-
-MICRO = {
-    # paper Fig. 8a row counts (scaled by the harness)
-    "filter_rows": 2_000_000_000,
-    "join_rows": 500_000,
-    "aggregate_rows": 256_000_000,
-    # Fig. 8b series length
-    "analytics_rows": 256_000_000,
-}

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import functools
 import os
+import re
 from dataclasses import dataclass
 from typing import Any
 
@@ -33,6 +34,7 @@ from . import physical_plan as pp
 from ..kernels import registry as kreg
 from .dtypes import NULL_CODE, categories_of, is_category, physical_dtype
 from .expr import ExternalArray, evaluate, nulltag_for
+from .spans import timed
 from .table import DTable, pad_to
 
 
@@ -196,6 +198,8 @@ class Lowered:
         self.P = int(np.prod([self.mesh.shape[a] for a in cfg.axes]))
         self.events: list = []   # degradation events picked up by RetryPolicy
         self.compiles = 0        # jit-cache misses (plan-cache hit => stays 0)
+        self._compiled = None    # the last compile()'s program
+        self._op_scopes = None
         self._build()
 
     # -- input marshalling ---------------------------------------------------
@@ -326,7 +330,8 @@ class Lowered:
                     vpairs.append((pre[1], _checksum_u32(out, cnt2)))
                 return out
 
-            for op in pplan.ops:
+            def emit(op):
+                """Trace one physical operator; returns its (cols, count)."""
                 n = op.node
                 ax = axes if op.dist != D.REP else ()
 
@@ -581,8 +586,14 @@ class Lowered:
 
                 else:
                     raise TypeError(op)
+                return res
 
-                env[op.op_id] = res
+            for op in pplan.ops:
+                # one named scope per operator: each HLO instruction's
+                # op_name metadata names the operator that emitted it, so a
+                # profile's device ops can be put down to operators.
+                with jax.named_scope(f"{type(op).__name__}.{op.op_id}"):
+                    env[op.op_id] = emit(op)
 
             cols, cnt = env[pplan.root_id]
             if validate:
@@ -722,12 +733,15 @@ class Lowered:
         if not hasattr(self, "_jit_cache"):
             self._jit_cache = {}
         if key not in self._jit_cache:
-            def wrapped(scan_cols, ext_cols):
+            # JAX's persistent compilation cache keys a program without its
+            # debug info, the operator scopes included, but with this name:
+            # it keeps these programs apart from ones compiled without them.
+            def hiframes_plan(scan_cols, ext_cols):
                 return self._per_shard({"scans": scan_cols, "ext": ext_cols,
                                         "rows": rows_static})
 
             shard_fn = jax.shard_map(
-                wrapped, mesh=mesh,
+                hiframes_plan, mesh=mesh,
                 in_specs=(self._in_specs["scans"], self._in_specs["ext"]),
                 out_specs=self._out_specs, check_vma=False)
             self._jit_cache[key] = jax.jit(shard_fn)
@@ -742,7 +756,8 @@ class Lowered:
         compiles of concurrent queries overlap."""
         fn, inputs = self._prepare(scan_nodes=scan_nodes, abstract=True)
         lowered = self._typed(lambda: fn.lower(inputs["scans"], inputs["ext"]))
-        return self._typed(lowered.compile)
+        self._compiled = self._typed(lowered.compile)
+        return self._compiled
 
     def hlo_text(self, optimized: bool = True) -> str:
         """The (optimized) HLO of the whole plan — used by the UDF-identity
@@ -753,22 +768,44 @@ class Lowered:
         return self._typed(
             lambda: fn.lower(inputs["scans"], inputs["ext"])).as_text()
 
+    def op_scopes(self) -> dict[str, str]:
+        """HLO instruction name -> label of the physical operator that
+        emitted it (``"MergeJoin.3"``: class and op id, the operator's
+        named scope), read once from the op_name metadata of the program
+        the last :meth:`compile` built (compiling only if none did).  A
+        profile's device ops are named by their instruction, so this puts
+        them down to operators.  Instructions outside every operator (the
+        plan's inputs and outputs, copies the compiler adds) are left
+        out."""
+        if self._op_scopes is None:
+            text = (self._compiled or self.compile()).as_text()
+            self._op_scopes = {m.group(1): m.group(2)
+                               for m in _SCOPED_INSTR.finditer(text)}
+        return self._op_scopes
+
     def __call__(self, scan_arrays: dict[str, dict[str, np.ndarray]] | None = None,
-                 scan_nodes=None):
+                 scan_nodes=None, phases: dict | None = None):
         """Execute.  scan_arrays overrides source columns by scan id (str);
-        scan_nodes rebinds scans to other same-shape tables (plan cache)."""
-        fn, inputs = self._prepare(scan_arrays, scan_nodes)
-        out = self._typed(lambda: fn(inputs["scans"], inputs["ext"]))
-        cap = self.pplan.root_op.cap
-        flags = np.asarray(out["overflow"]).reshape(self.P, -1)
-        reqs = np.asarray(out["ovf_req"]).reshape(self.P, -1)
-        overflow_ops = self._attribute_overflow(flags, reqs)
-        failures = self._check_invariants(out, overflow_ops)
-        return DTable(columns=out["cols"], counts=out["count"],
-                      capacity=cap, nshards=self.P, dist=self.dists[self.root.id],
-                      overflow=bool(flags.any()),
-                      overflow_ops=overflow_ops,
-                      invariant_failures=failures)
+        scan_nodes rebinds scans to other same-shape tables (plan cache).
+        ``phases`` (if given) gains the seconds of ``prepare`` (inputs
+        placed on the device), ``run`` (dispatch until the overflow flags
+        are on the host) and ``check`` (the host-side overflow and
+        invariant checks); each is also the host span ``hf.<phase>``."""
+        with timed("prepare", phases):
+            fn, inputs = self._prepare(scan_arrays, scan_nodes)
+        with timed("run", phases):
+            out = self._typed(lambda: fn(inputs["scans"], inputs["ext"]))
+            flags = np.asarray(out["overflow"]).reshape(self.P, -1)
+        with timed("check", phases):
+            reqs = np.asarray(out["ovf_req"]).reshape(self.P, -1)
+            overflow_ops = self._attribute_overflow(flags, reqs)
+            failures = self._check_invariants(out, overflow_ops)
+            return DTable(columns=out["cols"], counts=out["count"],
+                          capacity=self.pplan.root_op.cap, nshards=self.P,
+                          dist=self.dists[self.root.id],
+                          overflow=bool(flags.any()),
+                          overflow_ops=overflow_ops,
+                          invariant_failures=failures)
 
     def _typed(self, step):
         """Run a lower/compile/execute ``step``.  The TPU compiler refuses a
@@ -858,6 +895,14 @@ class Lowered:
                     fails.append(err.InvariantFailure(
                         kind, opid, f"{detail}: in={a} out={b}"))
         return tuple(fails)
+
+
+# an HLO instruction line whose op_name holds an operator's named scope
+# ("Class.<op id>" as one path element): group 1 the instruction, group 2 the
+# scope
+_SCOPED_INSTR = re.compile(
+    r'^\s*(?:ROOT )?%?([^\s=]+) = .*?metadata=\{[^}]*op_name="'
+    r'(?:[^"]*/)?([A-Z][A-Za-z]*\.\d+)(?:/[^"]*)?"', re.M)
 
 
 def _capacity_sites(pplan: pp.PhysicalPlan) -> list[tuple[int, str, str, str]]:

@@ -20,6 +20,7 @@ import jax
 import numpy as np
 
 from . import distribution as D
+from .spans import MONO, span
 
 # Logical dtypes live in core/dtypes.py; re-exported here because ingest
 # coercion (dictionary encode, null promotion) is part of the table contract.
@@ -48,6 +49,9 @@ class DTable:
     # retry/degradation events (runtime/retry.RetryEvent) from the policy
     # that produced this table — the collect report.
     events: tuple = ()
+    # the serving session's runtime/session.QueryRecord of the query that
+    # produced this table (None outside a session); to_numpy adds to it.
+    query_record: Any = None
 
     def __post_init__(self):
         if self.overflow_ops is None:
@@ -64,14 +68,27 @@ class DTable:
         return int(np.sum(counts))
 
     def to_numpy(self) -> dict[str, np.ndarray]:
-        """Gather valid rows to host (drops padding)."""
-        counts = np.asarray(self.counts)
-        shards = 1 if self.dist == D.REP else self.nshards
-        out: dict[str, np.ndarray] = {}
-        for name, col in self.columns.items():
-            a = np.asarray(col).reshape(self.nshards, self.capacity)
-            out[name] = np.concatenate(
-                [a[r, : counts[r]] for r in range(shards)]) if shards else a[:0]
+        """Gather valid rows to host (drops padding).  Every padded column
+        moves whole; with a ``query_record`` the seconds (host span
+        ``hf.readback``) and the column bytes moved are added to its
+        ``readback_s`` and ``readback_bytes``."""
+        t0 = MONO()
+        moved = 0
+        with span("readback"):
+            counts = np.asarray(self.counts)
+            shards = 1 if self.dist == D.REP else self.nshards
+            out: dict[str, np.ndarray] = {}
+            for name, col in self.columns.items():
+                a = np.asarray(col)
+                moved += a.nbytes
+                a = a.reshape(self.nshards, self.capacity)
+                out[name] = np.concatenate(
+                    [a[r, : counts[r]] for r in range(shards)]
+                ) if shards else a[:0]
+        rec = self.query_record
+        if rec is not None:
+            rec.readback_s += MONO() - t0
+            rec.readback_bytes += moved
         return out
 
     def column(self, name: str) -> jax.Array:

@@ -1,8 +1,8 @@
 """JAX's persistent compilation cache, for the program's entry points.
 
 A cold process compiles every plan again, which on a TPU takes seconds per
-plan.  The entry points (``chip_smoke.py``, ``repro.launch.serve``,
-``benchmarks.run``) call :func:`enable_compile_cache` once at start-up;
+plan.  The entry points (``chip_smoke.py``, ``repro.launch.serve``)
+call :func:`enable_compile_cache` once at start-up;
 library code never does, so importing the package changes no JAX setting.
 """
 from __future__ import annotations

@@ -23,8 +23,10 @@ A :class:`Session` owns the mesh for its lifetime and provides:
     ticket; host-side planning/lowering for distinct queries overlaps in a
     small worker pool while a mesh lock serializes device execution
     (SPMD collectives cannot interleave).  ``admission`` bounds queued
-    queries; each finished query carries a :class:`QueryRecord` with
-    timings, cache outcome, retry events, and the plan's collective count.
+    queries; each finished query carries a :class:`QueryRecord` with the
+    seconds of each phase, cache outcome, retry events, and the plan's
+    collective count.  Each phase is also a host span on the profiler's
+    clock (``hf.query``, ``hf.plan``, ``hf.lock``, ... — core/spans.py).
   * **Stats persistence** — the session scopes its own
     :class:`~repro.core.stats.StatsStore` (realized row counts + retry
     events) and persists it as ``<session_dir>/stats.json``, so a
@@ -56,6 +58,7 @@ from ..core import ir
 from ..core import stats as _st
 from ..core.api import DataFrame
 from ..core.lower import ExecConfig, Lowered, lower
+from ..core.spans import span, timed
 from . import retry as _rt
 from .reshard import reshard as _reshard
 
@@ -84,19 +87,53 @@ def cfg_signature(cfg: ExecConfig, P: int) -> tuple:
     return tuple(parts)
 
 
+# the phases of an execution (Session._execute, Lowered.__call__): exec_s
+# is their sum
+EXEC_PHASES = ("lock", "prepare", "run", "check")
+# Session.stats(): counts and phase seconds summed over finished queries
+_PHASE_TOTALS = ("queue", "plan", "exec") + EXEC_PHASES
+_TOTALS = ("queries", "compiles", "collectives") + tuple(
+    f"{p}_s" for p in _PHASE_TOTALS)
+
+
 @dataclass
 class QueryRecord:
-    """Per-query serving record (returned by :meth:`Session.collect` via
-    ``DTable.query_record`` and listed by :meth:`Session.stats`)."""
+    """Per-query serving record (``DTable.query_record`` of the answer).
+
+    Seconds on the monotonic clock, each also a host span ``hf.<phase>``
+    (docs/serving.md): ``queue_s`` from ``submit()`` to a worker's start
+    (admission and the pool's queue); ``plan_s`` the fingerprint, cache
+    lookup and scan rebind, plus lowering and compiling on a miss;
+    ``lock_s`` the wait for the mesh lock; ``prepare_s`` the inputs placed
+    on the device; ``run_s`` dispatch until the overflow flags are on the
+    host; ``check_s`` the host-side overflow and invariant checks.
+    ``exec_s`` is ``lock_s + prepare_s + run_s + check_s``, summed over
+    every execution of the query (a hit that falls back, retries).
+    ``DTable.to_numpy`` adds ``readback_s`` and ``readback_bytes``."""
 
     qid: int
     fingerprint: str
     cache: str = "miss"             # "hit" | "miss" | "hit_fallback"
-    plan_s: float = 0.0             # host-side planning, lowering, compile
-    exec_s: float = 0.0             # device execution (mesh lock held)
+    queue_s: float = 0.0
+    plan_s: float = 0.0
+    exec_s: float = 0.0
+    lock_s: float = 0.0
+    prepare_s: float = 0.0
+    run_s: float = 0.0
+    check_s: float = 0.0
+    readback_s: float = 0.0
+    readback_bytes: int = 0
     collectives: int = 0            # plan's all_to_all count per execution
     compiles: int = 0               # NEW jit entries this query caused
     events: tuple = ()
+    # the compiled plan that produced the answer (op_scopes(), hlo_text())
+    plan: Optional[Lowered] = field(default=None, repr=False, compare=False)
+
+    def add_exec(self, phases: dict) -> None:
+        """Add one execution's phase seconds (keys of ``EXEC_PHASES``)."""
+        for name, s in phases.items():
+            setattr(self, f"{name}_s", getattr(self, f"{name}_s") + s)
+        self.exec_s += sum(phases.values())
 
 
 @dataclass
@@ -183,8 +220,9 @@ class Session:
         self._admit = threading.BoundedSemaphore(max(admission, 1))
         self._pool = ThreadPoolExecutor(
             max_workers=max(workers, 1), thread_name_prefix="hf-serve")
-        self._records: list[QueryRecord] = []
-        self._records_lock = threading.Lock()
+        # running totals over finished queries (stats())
+        self._totals = dict.fromkeys(_TOTALS, 0)
+        self._totals_lock = threading.Lock()
         self._qid = 0
         self._register_collectives = 0
         self._closed = False
@@ -233,20 +271,21 @@ class Session:
         exchange / local sort) before persisting.  An already-persisted
         frame at a different shard count is resharded on device (split or
         merge, never a host gather)."""
-        node = df.node
-        q = df
-        if isinstance(node, ir.Scan) and node.layout is not None \
-                and node.layout.counts is not None:
-            if node.layout.nshards != self.P:
-                q = _reshard(df, self.P, self.cfg, name=name)
-            if partition_by or sort_by:
-                q = self._relayout(q, partition_by, sort_by, name)
-        else:
-            if partition_by:
-                q = q.repartition(partition_by)
-            if sort_by:
-                q = q.sort_within_partitions(sort_by)
-            q = self._persist(q, name)
+        with span("register"):
+            node = df.node
+            q = df
+            if isinstance(node, ir.Scan) and node.layout is not None \
+                    and node.layout.counts is not None:
+                if node.layout.nshards != self.P:
+                    q = _reshard(df, self.P, self.cfg, name=name)
+                if partition_by or sort_by:
+                    q = self._relayout(q, partition_by, sort_by, name)
+            else:
+                if partition_by:
+                    q = q.repartition(partition_by)
+                if sort_by:
+                    q = q.sort_within_partitions(sort_by)
+                q = self._persist(q, name)
         with self._tables_lock:
             self._tables[name] = q
         return q
@@ -291,11 +330,12 @@ class Session:
         queries are queued/in flight; further submits block."""
         if self._closed:
             raise RuntimeError("session is closed")
+        submitted = _MONO()
         self._admit.acquire()
 
         def run():
             try:
-                return self._run_query(df, cfg or self.cfg)
+                return self._run_query(df, cfg or self.cfg, submitted)
             finally:
                 self._admit.release()
 
@@ -306,7 +346,7 @@ class Session:
         return self.submit(df, cfg).result()
 
     def _next_qid(self) -> int:
-        with self._records_lock:
+        with self._totals_lock:
             self._qid += 1
             return self._qid
 
@@ -317,40 +357,58 @@ class Session:
         rep = df._force_rep()
         return tuple(n.id in rep for n in _topo_scans(df.node))
 
-    def _run_query(self, df: DataFrame, cfg: ExecConfig):
-        qid = self._next_qid()
-        fp = _st.plan_fingerprint(df.node, scans="shape")
-        key = (fp, cfg_signature(cfg, self.P), self._rep_key(df))
-        rec = QueryRecord(qid=qid, fingerprint=fp)
+    def _run_query(self, df: DataFrame, cfg: ExecConfig, submitted: float):
         t0 = _MONO()
-        entry = self.plan_cache.get(key)
-        if entry is not None and entry.rebindable:
-            t = self._try_hit(df, entry, rec, t0)
-            if t is not None:
-                self._finish(rec, t)
-                return t
-            rec.cache = "hit_fallback"
-        t = self._run_miss(df, cfg, key, rec, t0)
-        self._finish(rec, t)
-        return t
+        qid = self._next_qid()
+        with span("query", qid=qid):
+            with span("plan"):
+                fp = _st.plan_fingerprint(df.node, scans="shape")
+                key = (fp, cfg_signature(cfg, self.P), self._rep_key(df))
+                entry = self.plan_cache.get(key)
+                hit = entry is not None and entry.rebindable
+                scan_nodes = self._rebind(df, entry.lowered) if hit else None
+            rec = QueryRecord(qid=qid, fingerprint=fp, queue_s=t0 - submitted,
+                              plan_s=_MONO() - t0)
+            if hit:
+                t = self._try_hit(entry.lowered, scan_nodes, rec)
+                if t is not None:
+                    self._finish(rec, t)
+                    return t
+                rec.cache = "hit_fallback"
+            t = self._run_miss(df, cfg, key, rec)
+            self._finish(rec, t)
+            return t
 
-    def _try_hit(self, df: DataFrame, entry: _CacheEntry, rec: QueryRecord,
-                 t0: float):
-        """Replay the cached executable with this query's scan buffers.
-        Returns None when the entry cannot serve this query (falls back to
-        the miss path, which replaces the entry)."""
-        lowered = entry.lowered
+    @staticmethod
+    def _rebind(df: DataFrame, lowered: Lowered) -> dict | None:
+        """This query's scans by the cached plan's scan ids (positional);
+        None when the counts differ (the entry cannot serve the query)."""
         new_scans = _topo_scans(df.node)
         if len(new_scans) != len(lowered.scans):
             return None
-        scan_nodes = {str(s.id): new_scans[i]
-                      for i, s in enumerate(lowered.scans)}
-        before = lowered.compiles
-        rec.plan_s = _MONO() - t0
-        t1 = _MONO()
+        return {str(s.id): new_scans[i] for i, s in enumerate(lowered.scans)}
+
+    def _execute(self, lowered: Lowered, rec: QueryRecord, **kw):
+        """Run ``lowered`` under the mesh lock; the wait for the lock and
+        the execution's phases are added to ``rec``."""
+        phases: dict = {}
+        with timed("lock", phases):
+            self._mesh_lock.acquire()
         try:
-            with self._mesh_lock:
-                t = lowered(scan_nodes=scan_nodes)
+            return lowered(phases=phases, **kw)
+        finally:
+            self._mesh_lock.release()
+            rec.add_exec(phases)
+
+    def _try_hit(self, lowered: Lowered, scan_nodes, rec: QueryRecord):
+        """Replay the cached executable with this query's scan buffers.
+        Returns None when the entry cannot serve this query (falls back to
+        the miss path, which replaces the entry)."""
+        if scan_nodes is None:
+            return None
+        before = lowered.compiles
+        try:
+            t = self._execute(lowered, rec, scan_nodes=scan_nodes)
         except (ValueError, err.KernelBackendError, err.PlanInvariantError):
             return None
         if getattr(t, "overflow", False) or getattr(
@@ -358,40 +416,35 @@ class Session:
             # cached capacities were sized for a different table: replan
             return None
         rec.cache = "hit"
-        rec.exec_s = _MONO() - t1
+        rec.plan = lowered
         rec.collectives = lowered.pplan.collective_count()
         rec.compiles = lowered.compiles - before
         return t
 
-    def _run_miss(self, df: DataFrame, cfg: ExecConfig, key, rec: QueryRecord,
-                  t0: float):
+    def _run_miss(self, df: DataFrame, cfg: ExecConfig, key, rec: QueryRecord):
         """Full plan + retry-ladder execution; caches the survivor."""
         policy = _rt.RetryPolicy(max_retries=max(cfg.auto_retry, 0),
                                  scope=getattr(cfg, "retry_scope", "op"))
-
-        timings = {"plan": 0.0, "exec": 0.0}
 
         def run_once(c):
             # lowering and compiling (host-side) run outside the mesh lock
             # so other queries' planning and compiles overlap; execution
             # serializes.
             ta = _MONO()
-            lowered, _ = lower(df.node, c, force_rep=df._force_rep())
-            lowered.compile()
-            tb = _MONO()
-            timings["plan"] += tb - ta
-            with self._mesh_lock:
-                t = lowered()
-            timings["exec"] += _MONO() - tb
-            return lowered, t
+            with span("plan"):
+                with span("lower"):
+                    lowered, _ = lower(df.node, c, force_rep=df._force_rep())
+                with span("compile"):
+                    lowered.compile()
+            rec.plan_s += _MONO() - ta
+            return lowered, self._execute(lowered, rec)
 
         lowered, t, events, cfg2 = policy.execute(run_once, cfg)
         if events:
             _rt.record_events(lowered.root, events)
         if cfg2.adaptive_stats and not t.overflow:
             _st.record_realized(lowered.root, np.asarray(t.counts))
-        rec.plan_s = timings["plan"]
-        rec.exec_s = timings["exec"]
+        rec.plan = lowered
         rec.collectives = lowered.pplan.collective_count()
         rec.compiles = lowered.compiles
         rec.events = tuple(events)
@@ -415,26 +468,34 @@ class Session:
     def _finish(self, rec: QueryRecord, t) -> None:
         rec.events = rec.events or tuple(getattr(t, "events", ()) or ())
         t.query_record = rec
-        with self._records_lock:
-            self._records.append(rec)
+        with self._totals_lock:
+            tot = self._totals
+            tot["queries"] += 1
+            tot["compiles"] += rec.compiles
+            tot["collectives"] += rec.collectives
+            for p in _PHASE_TOTALS:
+                tot[f"{p}_s"] += getattr(rec, f"{p}_s")
 
     # -- introspection -------------------------------------------------------
 
     def stats(self) -> dict:
-        with self._records_lock:
-            recs = list(self._records)
+        """Plan-cache counters, and counts and phase seconds summed over
+        the queries finished so far (``phase_s``: ``queue``, ``plan``,
+        ``exec`` and its parts ``lock``, ``prepare``, ``run``, ``check``)."""
+        with self._totals_lock:
+            tot = dict(self._totals)
         pc = self.plan_cache
         return {
             "P": self.P,
-            "queries": len(recs),
+            "queries": tot["queries"],
             "plan_cache": {"hits": pc.hits, "misses": pc.misses,
                            "evictions": pc.evictions, "size": len(pc),
                            "capacity": pc.capacity},
-            "compiles": sum(r.compiles for r in recs),
-            "collectives": sum(r.collectives for r in recs),
+            "compiles": tot["compiles"],
+            "collectives": tot["collectives"],
             "register_collectives": self._register_collectives,
             "tables": sorted(self._tables),
-            "records": recs,
+            "phase_s": {p: tot[f"{p}_s"] for p in _PHASE_TOTALS},
         }
 
     def explain(self, df: DataFrame, cfg: ExecConfig | None = None) -> str:

@@ -14,9 +14,11 @@ import pytest
 
 from repro import hiframes as hf
 from repro.core import ir
+from repro.core import physical_plan as pp
 from repro.core import stats as st
 from repro.core.api import ExecConfig
 from repro.core.errors import StatsError
+from repro.core.lower import Lowered
 from repro.runtime.reshard import reshard
 from repro.runtime.session import PlanCache, Session, _CacheEntry, \
     cfg_signature
@@ -486,3 +488,231 @@ def test_serve_smoke_cli():
     assert res.returncode == 0, \
         f"stdout:\n{res.stdout[-2000:]}\nstderr:\n{res.stderr[-2000:]}"
     assert "serve smoke: PASS" in res.stdout
+
+
+# -- phases, spans and operator scopes ------------------------------------------
+
+EXEC_PARTS = ("lock_s", "prepare_s", "run_s", "check_s")
+
+
+def _join_query(sess):
+    s, i = sess.table("s"), sess.table("i")
+    j = s.merge(i, on=("k", "ik"))
+    return j.groupby("c").agg(n="count", w=("v", "sum"))
+
+
+def _join_session(**kw):
+    rng = np.random.default_rng(4)
+    sess = Session(ExecConfig(), **kw)
+    sess.register("s", hf.table(
+        {"k": rng.integers(0, 30, 500).astype(np.int32),
+         "c": rng.integers(0, 40, 500).astype(np.int32),
+         "v": rng.normal(size=500).astype(np.float32)}, "s"),
+        partition_by="k")
+    sess.register("i", hf.table({"ik": np.arange(30, dtype=np.int32)},
+                                "i").replicate())
+    return sess
+
+
+@pytest.mark.parametrize("cache", ["miss", "hit"])
+def test_exec_phases_sum_to_exec_s(cache):
+    with _join_session() as sess:
+        t = sess.collect(_join_query(sess))
+        if cache == "hit":
+            t = sess.collect(_join_query(sess))
+        rec = t.query_record
+        assert rec.cache == cache
+        assert all(getattr(rec, p) > 0 for p in EXEC_PARTS)
+        assert rec.exec_s == pytest.approx(
+            sum(getattr(rec, p) for p in EXEC_PARTS), rel=1e-12)
+        assert rec.plan_s > 0 and rec.queue_s > 0
+        assert rec.plan is not None
+
+
+def test_hit_plan_s_includes_the_fingerprint(monkeypatch):
+    import time
+    from repro.runtime import session as session_mod
+    fingerprint = session_mod._st.plan_fingerprint
+
+    def slow(*a, **k):
+        time.sleep(0.05)
+        return fingerprint(*a, **k)
+
+    with _join_session() as sess:
+        sess.collect(_join_query(sess))
+        monkeypatch.setattr(session_mod._st, "plan_fingerprint", slow)
+        rec = sess.collect(_join_query(sess)).query_record
+        assert rec.cache == "hit"
+        assert rec.plan_s >= 0.05
+
+
+def test_queue_s_grows_when_admission_is_saturated():
+    """With one admission slot held by a query waiting on the mesh lock,
+    the next submit waits in admission; its queue_s covers that wait."""
+    import threading
+    import time
+    mono = time.monotonic
+    with _join_session(admission=1, workers=2) as sess:
+        sess.collect(_join_query(sess))                     # compile
+        sess._mesh_lock.acquire()
+        first = sess.submit(_join_query(sess))              # takes the slot
+        second, sent = [], []
+
+        def submit_second():
+            sent.append(mono())
+            second.append(sess.submit(_join_query(sess)))
+
+        th = threading.Thread(target=submit_second)
+        th.start()
+        time.sleep(0.3)
+        released = mono()
+        sess._mesh_lock.release()
+        th.join(timeout=60)
+        assert not th.is_alive()
+        a = first.result(timeout=60).query_record
+        b = second[0].result(timeout=60).query_record
+        assert a.lock_s > 0
+        # the second waited from its submit until the first let go
+        assert b.queue_s >= released - sent[0] > 0.1
+
+
+def test_readback_counts_the_padded_column_bytes():
+    with _join_session() as sess:
+        t = sess.collect(_join_query(sess))
+        rec = t.query_record
+        assert rec.readback_s == 0 and rec.readback_bytes == 0
+        padded = sum(t.nshards * t.capacity * np.dtype(c.dtype).itemsize
+                     for c in t.columns.values())
+        t.to_numpy()
+        assert rec.readback_bytes == padded
+        assert rec.readback_s > 0
+        t.to_numpy()                                        # moves it again
+        assert rec.readback_bytes == 2 * padded
+
+
+def test_stats_sums_the_finished_queries():
+    with _join_session() as sess:
+        recs = [sess.collect(_join_query(sess)).query_record
+                for _ in range(3)]
+        st_ = sess.stats()
+        assert "records" not in st_
+        assert st_["queries"] == 3
+        assert st_["compiles"] == sum(r.compiles for r in recs)
+        assert st_["collectives"] == sum(r.collectives for r in recs)
+        for p in ("queue", "plan", "exec", "lock", "prepare", "run",
+                  "check"):
+            assert st_["phase_s"][p] == pytest.approx(
+                sum(getattr(r, f"{p}_s") for r in recs))
+
+
+def _label(op):
+    return f"{type(op).__name__}.{op.op_id}"
+
+
+@pytest.mark.parametrize("query", ["join_aggregate", "sort_window"])
+def test_every_operator_label_is_in_the_hlo_metadata(query):
+    """Each physical operator that computes names its instructions through
+    op_name metadata, and op_scopes() maps them.  Sources, maps and
+    aggregate preparation may only forward or rename columns (a source's
+    enter as the program's parameters), which leaves no instruction."""
+    with _join_session() as sess:
+        if query == "join_aggregate":
+            q = _join_query(sess)
+        else:
+            srt = sess.table("s").sort("c")
+            q = hf.cumsum(srt, srt["v"], out="cs")
+        low = sess.collect(q).query_record.plan
+        text = low.hlo_text()
+        labels = set(low.op_scopes().values())
+        ops = [op for op in low.pplan.ops
+               if not isinstance(op, (pp.Source, pp.Map, pp.AggPrep))]
+        assert len(ops) >= 2
+        for op in ops:
+            assert f"/{_label(op)}/" in text, _label(op)
+            assert _label(op) in labels, _label(op)
+        assert labels <= {_label(op) for op in low.pplan.ops}
+
+
+def test_op_scopes_read_the_program_the_session_compiled(monkeypatch):
+    """The miss path's compile() is the one op_scopes() reads: asking for
+    the scopes after the query compiles nothing again."""
+    with _join_session() as sess:
+        low = sess.collect(_join_query(sess)).query_record.plan
+
+        def no_compile(*a, **k):
+            raise AssertionError("op_scopes() compiled the plan again")
+
+        monkeypatch.setattr(Lowered, "compile", no_compile)
+        labels = set(low.op_scopes().values())
+        assert labels and labels <= {_label(op) for op in low.pplan.ops}
+
+
+def test_span_records_nothing_and_raises_nothing_without_a_trace():
+    from repro.core.spans import span, timed
+
+    class Unprintable:
+        def __str__(self):
+            raise AssertionError("formatted with no trace active")
+        __repr__ = __str__
+
+    with span("query", qid=Unprintable()):
+        pass
+    acc = {}
+    with timed("run", acc):
+        pass
+    with pytest.raises(KeyError):
+        with timed("run", acc):
+            raise KeyError("x")
+    assert set(acc) == {"run"} and acc["run"] > 0
+
+
+def test_spans_land_in_a_profiler_trace(tmp_path):
+    """On the CPU too: a query through the session writes its hf.* spans
+    into the trace, the execution's phases nested in hf.query."""
+    import glob
+    import jax
+    from jax.profiler import ProfileData
+    with _join_session() as sess:
+        sess.collect(_join_query(sess))
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            sess.collect(_join_query(sess)).to_numpy()
+        finally:
+            jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    spans = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("hf."):
+                    spans.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns))
+    assert set(spans) == {"hf.query", "hf.plan", "hf.lock", "hf.prepare",
+                          "hf.run", "hf.check", "hf.readback"}
+    (q0, q1), = spans["hf.query"]
+    for name in ("hf.plan", "hf.lock", "hf.prepare", "hf.run", "hf.check"):
+        (s, e), = spans[name]
+        assert q0 <= s <= e <= q1, name
+    (r0, _), = spans["hf.readback"]
+    assert r0 >= q1
+
+
+def test_totals_under_concurrent_queries():
+    """More workers than cores and a short switch interval: the running
+    totals lose no update."""
+    interval = sys.getswitchinterval()
+    with _join_session(workers=2 * (os.cpu_count() or 4),
+                       admission=64) as sess:
+        first = sess.collect(_join_query(sess)).query_record  # compile
+        sys.setswitchinterval(1e-6)
+        try:
+            futs = [sess.submit(_join_query(sess)) for _ in range(48)]
+            recs = [f.result(timeout=120).query_record for f in futs]
+        finally:
+            sys.setswitchinterval(interval)
+        st_ = sess.stats()
+        assert st_["queries"] == 49
+        assert len({r.qid for r in recs}) == 48
+        assert st_["phase_s"]["exec"] == pytest.approx(
+            first.exec_s + sum(r.exec_s for r in recs))
+        assert st_["plan_cache"]["hits"] == 48

@@ -414,15 +414,20 @@ def merge_join(lcols, lcount, rcols, rcount, lkeys, rkeys, *,
     or more key columns.  Inputs do NOT need to be pre-sorted.
 
     Both sides' key columns are concatenated and sorted ONCE as tuples
-    (:func:`lex_ranks`); the same sort yields (a) a dense rank per row and
-    (b) the right side's key-sorted permutation.  Expansion trick: per-left-
-    row match counts -> prefix sums -> each output slot s maps back to
-    (left row, offset within its match range) with two searchsorteds into
-    the rank arrays; matched right rows are gathered through the
-    permutation.  Output rows follow LEFT row order, so a sorted left input
-    yields key-sorted output.  Left-outer: unmatched rows get count 1 and
-    zero-filled right columns plus a ``_matched`` indicator (the
-    static-shape NULL).  Fully static shapes; overflow flagged.
+    (:func:`lex_ranks`).  The sort is stable on the union index, so within
+    a run of equal keys every left row precedes every right row: the
+    running count of right rows at a left row's sorted position is the
+    start ``lo`` of its match range in key-sorted right order, and ``hi``
+    adds the run's right rows, counted per dense rank by one scatter-add.
+    Expansion: per-left-row match counts -> exclusive prefix sums (each
+    row's first output slot); a scatter-add of one at every first slot and
+    a prefix sum over the slots give each slot its left row, with no
+    search; matched right rows are gathered through the key-sorted right
+    permutation, from the same sort.  Output rows follow LEFT row order,
+    so a sorted left input yields key-sorted output.
+    Left-outer: unmatched rows get count 1 and zero-filled right columns
+    plus a ``_matched`` indicator (the static-shape NULL).  Fully static
+    shapes, O(lcap + rcap + cap_out) work with no loop; overflow flagged.
     """
     if isinstance(lkeys, str):
         lkeys = (lkeys,)
@@ -441,23 +446,29 @@ def merge_join(lcols, lcount, rcols, rcount, lkeys, rkeys, *,
         dt = jnp.promote_types(la.dtype, ra.dtype)
         keycols.append(jnp.concatenate([la.astype(dt), ra.astype(dt)]))
     ranks, sidx, rank_sorted = lex_ranks(keycols, valid)
-    lrank = ranks[:lcap]
+    n = lcap + rcap
 
     # right rows in key-sorted order, extracted from the SAME sort: a stable
     # compaction of the sorted union down to right-side entries.
     is_r = (sidx >= lcap).astype(jnp.int32)
-    pos_r = jnp.cumsum(is_r) - 1
-    scat = jnp.where(is_r > 0, pos_r, lcap + rcap)
-    rsorted_rank = jnp.full((rcap,), _sentinel(jnp.int32)) \
-        .at[scat].set(rank_sorted, mode="drop")
+    cum_r = jnp.cumsum(is_r)
+    scat = jnp.where(is_r > 0, cum_r - 1, n)
     rperm = jnp.zeros((rcap,), jnp.int32) \
         .at[scat].set((sidx - lcap).astype(jnp.int32), mode="drop")
 
-    lo = jnp.searchsorted(rsorted_rank, lrank, side="left")
-    hi = jnp.searchsorted(rsorted_rank, lrank, side="right")
-    hi = jnp.minimum(hi, rcount)
+    # match ranges.  The sort is stable on the union index, so a left row
+    # precedes every right row of its key run: the right rows counted up to
+    # it are those before the run (lo); hi adds the run's right rows,
+    # counted per dense rank.  Invalid right rows sort last (sentinel key,
+    # highest index), so the rcount clamp keeps sentinel-valued keys off them.
+    run_r = jnp.zeros((n,), jnp.int32).at[rank_sorted].add(
+        is_r, indices_are_sorted=True)
+    # sidx is a permutation: right rows (sidx >= lcap) drop off the end.
+    lo = jnp.zeros((lcap,), jnp.int32).at[sidx].set(
+        cum_r, mode="drop", unique_indices=True)
+    hi = jnp.minimum(lo + run_r.at[ranks[:lcap]].get(mode="clip"), rcount)
     lo = jnp.minimum(lo, rcount)
-    matches = (hi - lo).astype(jnp.int32)
+    matches = hi - lo
     cnt = jnp.where(lvalid, matches, 0)
     if how == "left":
         cnt = jnp.where(lvalid & (matches == 0), 1, cnt)
@@ -467,9 +478,12 @@ def merge_join(lcols, lcount, rcols, rcount, lkeys, rkeys, *,
     total = incl[-1] if lcap else jnp.int32(0)
     overflow = total > cap_out
 
+    # slot s belongs to row i with excl[i] <= s < incl[i]; excl ascends, so
+    # i + 1 is the number of rows whose first slot is <= s.
     s = jnp.arange(cap_out, dtype=jnp.int32)
-    li = jnp.searchsorted(incl, s, side="right")
-    li_c = jnp.clip(li, 0, lcap - 1)
+    first = jnp.zeros((cap_out,), jnp.int32).at[excl].add(
+        1, mode="drop", indices_are_sorted=True)
+    li_c = jnp.clip(jnp.cumsum(first) - 1, 0, lcap - 1)
     matched = matches[li_c] > 0
     rpos = lo[li_c] + (s - excl[li_c])          # position in key-sorted right
     ri_c = rperm[jnp.clip(rpos, 0, rcap - 1)]   # original right row

@@ -5,11 +5,13 @@ limits must refuse.
 
     python3 chipbench/control.py --workload per_item-serve --seeds 1 2 3
 
-* float sums (``per_item``): the configuration states float32; the control
-  computes the sums on the device in bfloat16 (inputs and accumulator);
-* exact answers (``q26``): no float is compared, so the control breaks the
-  guarantee of an answer exact over the registered table: it answers from
-  a stale snapshot that misses the table's last ``STALE_ROWS`` rows.
+* a query module's own ``control(host)``, where it defines one: for float
+  sums (``per_item``), the sums computed on the device in bfloat16 where
+  the configuration states float32;
+* otherwise, for exact answers (``q26``): no float is compared, so the
+  control breaks the guarantee of an answer exact over the registered
+  tables: it answers from a stale snapshot that misses the last
+  ``STALE_ROWS`` rows of the configuration's partitioned fact table.
 
 Per seed it prints each compared number of the control beside its limit
 and whether the limit refuses it.  The benchmark's runs never run it.  It
@@ -22,8 +24,6 @@ import argparse
 import os
 import sys
 
-import numpy as np
-
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from chipbench import run as bench  # noqa: E402  (sets up the program's path)
 
@@ -32,40 +32,38 @@ from chipbench import check, data  # noqa: E402
 STALE_ROWS = 1200
 
 
-def bf16_sums(host: dict) -> dict:
-    """``per_item`` computed in bfloat16 on the default device."""
-    import jax
-    import jax.numpy as jnp
-    ss = host["store_sales"]
-    k = jnp.asarray(ss["ss_item_sk"])
-    n_seg = int(ss["ss_item_sk"].max()) + 1
-    paid = jax.ops.segment_sum(jnp.asarray(ss["ss_net_paid"], jnp.bfloat16),
-                               k, num_segments=n_seg)
-    n = jax.ops.segment_sum(jnp.ones_like(k), k, num_segments=n_seg)
-    n = np.asarray(n)
-    items = np.nonzero(n)[0]
-    return {"ss_item_sk": items, "n": n[items],
-            "paid": np.asarray(paid.astype(jnp.float32))[items]}
+def fact_table(cell) -> str:
+    """The configuration's partitioned table: the one with ``partition_by``."""
+    facts = [name for name, lay in cell.config["tables"].items()
+             if lay.get("partition_by")]
+    if len(facts) != 1:
+        raise ValueError(f"{len(facts)} partitioned tables in "
+                         f"{list(cell.config['tables'])}; a stale snapshot "
+                         "needs one")
+    return facts[0]
 
 
 def stale(cell, host: dict) -> dict:
-    """The query's reference over the table without its last rows."""
-    ss = {c: v[:-STALE_ROWS] for c, v in host["store_sales"].items()}
-    return cell.query.reference({**host, "store_sales": ss})
+    """The query's reference over the fact table without its last rows."""
+    fact = fact_table(cell)
+    trimmed = {c: v[:-STALE_ROWS] for c, v in host[fact].items()}
+    return cell.query.reference({**host, fact: trimmed})
 
 
 def control_answer(cell, host: dict) -> dict:
+    if hasattr(cell.query, "control"):
+        return cell.query.control(host)
     if cell.query.FLOAT:
-        if cell.traffic["query"] != "per_item":
-            raise NotImplementedError(
-                f"no bfloat16 control for {cell.traffic['query']!r}")
-        return bf16_sums(host)
+        raise NotImplementedError(
+            f"no control for {cell.traffic['query']!r}: a query with float "
+            "columns defines its own control(host)")
     return stale(cell, host)
 
 
 def readings(cell, seed: int, scale: dict | None = None) -> dict:
     """The control's compared numbers on the tables of ``seed``."""
-    host = data.make_tables(scale or cell.config["scale"], seed)
+    host = data.make_tables(cell.root, cell.config["tables"],
+                            scale or cell.config["scale"], seed)
     want = cell.query.reference(host)
     numbers, _ = check.compare(cell.query, [control_answer(cell, host)],
                                want, cell.limits)

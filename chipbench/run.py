@@ -27,7 +27,6 @@ T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import gc  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
@@ -39,33 +38,9 @@ for p in (os.path.join(ROOT, "src"), ROOT):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-from chipbench import check, data, loops  # noqa: E402
+from chipbench import check, data, load_json, load_module, loops  # noqa: E402
 
 SPANS = ("submit", "result", "to_numpy")
-
-
-# -- the benchmark's files, found by name ------------------------------------
-
-def load_json(path: str):
-    with open(path) as f:
-        return json.load(f)
-
-
-def load_module(root: str, kind: str, name: str):
-    """``chipbench/<kind>/<name>.py``; a metric split by the end-to-end
-    metric it moves (``exec_ms.batch``, ``exec_ms.serve``) falls back to one
-    reader for the quantity (``exec_ms.py``)."""
-    d = os.path.join(root, "chipbench", kind)
-    path = os.path.join(d, f"{name}.py")
-    if kind == "metrics" and not os.path.exists(path):
-        path = os.path.join(d, f"{name.split('.')[0]}.py")
-    if not os.path.exists(path):
-        raise FileNotFoundError(f"no {kind} file for {name!r} in {d}")
-    spec = importlib.util.spec_from_file_location(
-        f"chipbench_{kind}_{name.replace('.', '_').replace('-', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 class Cell:
@@ -159,7 +134,8 @@ def measure(cell: Cell, seed: int, seconds: float, traced: bool,
     mesh_devs = devices[:cell.chips]
     mesh = Mesh(np.array(mesh_devs), ("data",))
     phases = [("start-up", time.perf_counter())]
-    host = data.make_tables(cell.config["scale"], seed)
+    host = data.make_tables(cell.root, cell.config["tables"],
+                            cell.config["scale"], seed)
     phases.append(("tables", time.perf_counter()))
     run = Run(cell, seconds)
     sess = Session(ExecConfig(mesh=mesh))
@@ -279,8 +255,9 @@ def listing(root: str) -> str:
         for m in spec[g]:
             cells = ", ".join(m.get("workloads", ["every cell"]))
             lines.append(f"  {m['name']} [{m['unit']}, {m['better']}]: {cells}")
-    for kind, ext in (("configs", ".json"), ("traffic", ".json"),
-                      ("queries", ".py"), ("metrics", ".py")):
+    for kind, ext in (("configs", ".json"), ("tables", ".py"),
+                      ("traffic", ".json"), ("queries", ".py"),
+                      ("metrics", ".py")):
         d = os.path.join(root, "chipbench", kind)
         names = sorted(f[:-len(ext)] for f in os.listdir(d)
                        if f.endswith(ext) and not f.startswith("_"))

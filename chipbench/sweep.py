@@ -49,7 +49,8 @@ def main(argv=None) -> int:
         return 1
     bench.enable_compile_cache(jax)
     mesh = Mesh(np.array(devices[:cell.chips]), ("data",))
-    host = bench.data.make_tables(cell.config["scale"], args.seeds[0])
+    host = bench.data.make_tables(cell.root, cell.config["tables"],
+                                  cell.config["scale"], args.seeds[0])
     with Session(ExecConfig(mesh=mesh)) as sess:
         tables = bench.register(sess, hf, cell, host)
 

@@ -13,7 +13,7 @@ import pytest
 from chipbench import check, control
 from chipbench import run as bench
 
-from .conftest import REPO, TINY, copy_bench
+from .conftest import REPO, TINY, copy_bench, tiny_scale
 
 CMD = ["chipbench/run.py", "--workload", "q26-batch", "--seed", "7",
        "--seconds", "1", "--trace", "0"]
@@ -34,7 +34,7 @@ def test_exits_nonzero_without_a_tpu():
 
 
 def test_exits_nonzero_beside_its_own_files_alone(tmp_path):
-    root = copy_bench(str(tmp_path), scale=None)        # no src/ beside it
+    root = copy_bench(str(tmp_path), tiny=False)        # no src/ beside it
     p = subprocess.run([sys.executable, *CMD], cwd=root,
                        env=cpu_env(PYTHONPATH=""), capture_output=True,
                        text=True, timeout=300)
@@ -120,6 +120,135 @@ def test_new_files_are_found_by_name(tiny_root):
                              "exec_ms.batch").__file__.endswith("exec_ms.py")
 
 
+def test_tiny_copy_cuts_each_config_by_its_tables(tiny_root):
+    """The committed configurations are cut to the same small tables as
+    before the table files declared their own tiny scales."""
+    spec = read_json(os.path.join(tiny_root, "BENCHMARK.json"))
+    for c in spec["configs"]:
+        cfg = read_json(os.path.join(tiny_root, c["file"]))
+        assert cfg["scale"] == TINY, c["name"]
+
+
+# A table of clicks whose item key follows a Zipf law of the exponent its
+# spec gives, with a query over it and the item table.
+CLICKS_TABLE = '''
+import numpy as np
+from chipbench import data
+
+SEED_OFFSET = 2
+TINY = {"clicks_rows": 8000, "items": 120, "users": 500}
+
+
+def make(scale, spec, seed):
+    rng = np.random.default_rng(seed)
+    n = scale["clicks_rows"]
+    return {"c_item_sk": data.zipf_keys(rng, n, scale["items"], spec["zipf"]),
+            "c_user_sk": rng.integers(0, scale["users"], n).astype(np.int32)}
+'''
+
+CLICKS_QUERY = '''
+import numpy as np
+
+KEY = "i_category_id"
+EXACT = ("clicks",)
+FLOAT = ()
+
+
+def build(hf, t):
+    j = t["tiny_clicks"].merge(t["item"], on=("c_item_sk", "i_item_sk"))
+    return j.groupby("i_category_id").agg(clicks="count")
+
+
+def reference(h):
+    it = h["item"]
+    cat = np.zeros(int(it["i_item_sk"].max()) + 1, np.int64)
+    cat[it["i_item_sk"]] = it["i_category_id"]
+    n = np.bincount(cat[h["tiny_clicks"]["c_item_sk"]])
+    keep = np.nonzero(n)[0]
+    return {"i_category_id": keep, "clicks": n[keep]}
+
+
+def control(h):
+    """The counts kept in int8, which wraps."""
+    want = reference(h)
+    return {**want, "clicks": want["clicks"].astype(np.int8)}
+'''
+
+
+def add_skewed_cell(root):
+    """A configuration whose fact table is neither store_sales nor item, with
+    a Zipf item key, its query, traffic and limit, as new files and new
+    BENCHMARK.json entries; no committed file is edited."""
+    b = os.path.join(root, "chipbench")
+    with open(os.path.join(b, "tables", "tiny_clicks.py"), "w") as f:
+        f.write(CLICKS_TABLE)
+    with open(os.path.join(b, "queries", "clicks_per_category.py"), "w") as f:
+        f.write(CLICKS_QUERY)
+    tables = {"tiny_clicks": {"partition_by": "c_item_sk", "zipf": 1.1},
+              "item": {"replicate": True}}
+    write_json(os.path.join(b, "configs", "tiny-skew.json"),
+               {"name": "tiny-skew", "scale": tiny_scale(root, tables),
+                "tables": tables})
+    write_json(os.path.join(b, "traffic", "clicks-closed.json"),
+               {"query": "clicks_per_category", "loop": "closed",
+                "warmup": 1})
+    write_json(os.path.join(b, "limits",
+                            "tiny-skew.clicks_per_category.json"),
+               {"wrong_rows": 0})
+    spec_p = os.path.join(root, "BENCHMARK.json")
+    spec = read_json(spec_p)
+    spec["configs"].append({"name": "tiny-skew", "source": "test",
+                            "file": "chipbench/configs/tiny-skew.json",
+                            "reduced": [], "why": "test"})
+    spec["workloads"].append({"name": "skew-cell", "config": "tiny-skew",
+                              "traffic": "clicks-closed", "chips": 1,
+                              "why": "test"})
+    write_json(spec_p, spec)
+
+
+def test_a_config_brings_its_own_tables(tiny_root):
+    before = committed_files(tiny_root)
+    add_skewed_cell(tiny_root)
+    after = committed_files(tiny_root)
+    assert all(after[k] == v for k, v in before.items())
+    assert "tiny_clicks" in bench.listing(tiny_root)
+    cell = bench.Cell(tiny_root, "skew-cell")
+    assert cell.config["scale"] == {"clicks_rows": 8000, "items": 120,
+                                    "users": 500}
+    host = bench.data.make_tables(tiny_root, cell.config["tables"],
+                                  cell.config["scale"], 2**33 + 1)
+    assert list(host) == ["tiny_clicks", "item"]
+    # the hottest key holds its Zipf share, 0.2277 of the rows for s = 1.1
+    # over 120 keys, where a uniform key would hold about 1/120
+    keys = host["tiny_clicks"]["c_item_sk"]
+    assert np.bincount(keys).max() / len(keys) == pytest.approx(0.2277,
+                                                                abs=1e-3)
+    out = bench.measure(cell, 2**33 + 1, 0.5, False, jax.devices())
+    assert out["correct"], out["checks"]
+    assert out["attempted"] > 0 and out["failed"] == 0
+
+
+def test_a_query_brings_its_own_control(tiny_root):
+    """The new query's control is found by name and refused by its limits;
+    the stale snapshot trims the new configuration's partitioned table."""
+    add_skewed_cell(tiny_root)
+    cell = bench.Cell(tiny_root, "skew-cell")
+    assert control.fact_table(cell) == "tiny_clicks"
+    for seed in (1, 2, 3):
+        host = bench.data.make_tables(tiny_root, cell.config["tables"],
+                                      cell.config["scale"], seed)
+        assert control.control_answer(cell, host)["clicks"].dtype == np.int8
+        numbers = control.readings(cell, seed)
+        assert numbers["wrong_rows"] > 0
+        assert not check.judge(numbers, cell.limits, 0)[0]
+        got = control.stale(cell, host)
+        n = len(host["tiny_clicks"]["c_item_sk"]) - control.STALE_ROWS
+        assert got["clicks"].sum() == n
+        numbers, _ = check.compare(cell.query, [got],
+                                   cell.query.reference(host), cell.limits)
+        assert not check.judge(numbers, cell.limits, 0)[0]
+
+
 @pytest.mark.parametrize("workload", ["q26-batch", "per_item-serve"])
 def test_sound_run_is_correct(tiny_root, workload):
     out = bench.measure(bench.Cell(tiny_root, workload), 2**31 + 3, 1.0,
@@ -190,26 +319,10 @@ print(json.dumps(out["correct"]))
 """
 
 
-def add_four_chip_cell(root):
-    """``q26-batch-4chip`` over ``tpcxbb-sf1-4chip``, whose files are in
-    ``chipbench/``, as BENCHMARK.json entries."""
-    spec_p = os.path.join(root, "BENCHMARK.json")
-    spec = read_json(spec_p)
-    spec["configs"].append({"name": "tpcxbb-sf1-4chip", "source": "test",
-                            "file": "chipbench/configs/tpcxbb-sf1-4chip.json",
-                            "reduced": [], "why": "test"})
-    spec["workloads"].append({"name": "q26-batch-4chip",
-                              "config": "tpcxbb-sf1-4chip",
-                              "traffic": "q26-closed", "chips": 4,
-                              "why": "test"})
-    write_json(spec_p, spec)
-
-
 @pytest.mark.parametrize("fault", [False, True], ids=["sound", "no_exchange"])
 def test_exchange_left_out_is_not_correct(tiny_root, fault):
     """Four virtual CPU devices: the sound run is correct, the one whose
     exchange keeps every row on its own shard is not."""
-    add_four_chip_cell(tiny_root)
     script = FOUR_CHIPS.format(repo=REPO, root=tiny_root, fault=fault)
     env = cpu_env(XLA_FLAGS="--xla_force_host_platform_device_count=4",
                   PYTHONPATH=os.path.join(REPO, "src"))

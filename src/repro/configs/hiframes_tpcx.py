@@ -33,6 +33,12 @@ class TpcxConfig:
 SF100 = TpcxConfig("sf100", 120_000_000, 178_000, 990_000, 390_000_000)
 SF1000 = TpcxConfig("sf1000", 1_200_000_000, 500_000, 5_000_000,
                     3_900_000_000)
+# skew=1.1 here is what ``repro.data.synth`` draws: ``rng.zipf(1 + skew)``,
+# a Zipf law of exponent 2.1, unbounded and folded modulo the items, which
+# puts about 64% (1/zeta(2.1)) of the clicks on one item.  The chip
+# benchmark's Q05 deployment (chipbench/configs/tpcxbb-q05-zipf.json)
+# instead draws ``chipbench.data.zipf_keys`` with the exponent 1.1 itself,
+# bounded to the items: 14.65% on the hottest of SF1's 17,820.
 Q05_SKEWED = TpcxConfig("q05skew", 120_000_000, 178_000, 990_000,
                         390_000_000, skew=1.1)
 

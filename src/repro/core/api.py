@@ -73,9 +73,9 @@ import numpy as np
 
 from . import distribution as D
 from . import ir
-from .dtypes import (CODE_DTYPE, DType, NULL_CODE, as_nullable, categories_of,
-                     coerce_column, dict_decode, is_category, is_nullable,
-                     physical_dtype, recode_map, union_categories)
+from .dtypes import (CODE_DTYPE, DType, NULL_CODE, as_nullable, carry_logical,
+                     categories_of, coerce_column, dict_decode, is_category,
+                     is_nullable, physical_dtype, recode_map, union_categories)
 from .expr import (AGG_FNS, AggExpr, BinOp, Cast, ColRef, Const, Expr, IsIn,
                    UDF, UnOp, all_, any_, as_expr, count, first, fn_expr,
                    max_, mean, min_, nunique, prod, std, sum_, var)
@@ -676,7 +676,9 @@ class DataFrame:
         different device count falls back to a host gather and a plain
         block scan (correct, just not elided).  Replicated results re-enter
         as host tables pinned REP — a persisted dimension table keeps
-        broadcasting.
+        broadcasting.  Either way the new scan keeps this frame's logical
+        dtypes: category columns stay ``category[str]`` over the same
+        dictionary, nullable columns stay nullable.
         """
         cfg = cfg or ExecConfig()
         lowered, t = self._execute(cfg)
@@ -718,14 +720,19 @@ class DataFrame:
             order_ascending=root_op.order.ascending,
             counts=np.asarray(t.counts, dtype=np.int32),
             capacity=int(t.capacity), nshards=int(t.nshards), dist=t.dist)
+        # the arrays hold codes and raw values: the frame's schema keeps
+        # their dictionaries and nullability (docs/dtypes.md)
         if t.dist == D.REP:
             # replicated results are tiny by construction: re-enter as a
             # plain host table pinned REP, keeping the ordering contract.
-            scan = ir.Scan(name, t.to_numpy(),
+            cols = t.to_numpy()
+            scan = ir.Scan(name, cols, carry_logical(cols, self.node.schema),
                            layout=_dc.replace(layout, kind="rep",
                                               counts=None))
             return DataFrame(scan, frozenset({scan.id}))
-        scan = ir.Scan(name, dict(t.columns), layout=layout)
+        cols = dict(t.columns)
+        scan = ir.Scan(name, cols, carry_logical(cols, self.node.schema),
+                       layout=layout)
         return DataFrame(scan)
 
     def cache(self, cfg: ExecConfig | None = None, *,

@@ -101,6 +101,29 @@ def categories_of(dt) -> tuple:
     return dt.categories
 
 
+def carry_logical(columns: dict, logical: dict) -> dict:
+    """Schema of materialized ``columns`` (in their order) that keeps the
+    category dictionaries and nullability ``logical`` gives them; every
+    other column takes its array's dtype, as a scan derives it.  Used where
+    a scan is rebuilt from a plan's output arrays (``persist``,
+    ``reshard``), whose dtypes alone are physical."""
+    out = {}
+    for k, v in columns.items():
+        phys = np.dtype(v.dtype)
+        dt = logical.get(k)
+        out[k] = (DType(phys, dt.categories, dt.nullable)
+                  if isinstance(dt, DType) else phys)
+    return out
+
+
+def logical_key(dt) -> tuple:
+    """``(physical dtype, dictionary or None, nullable)`` of a schema
+    entry: equal keys are one logical dtype.  (``np.dtype == DType``
+    compares physical dtypes alone, so compare these instead.)"""
+    return (np.dtype(dt), dt.categories if is_category(dt) else None,
+            is_nullable(dt))
+
+
 def as_nullable(dt) -> Any:
     """The nullable variant of a schema entry (idempotent)."""
     if isinstance(dt, DType):

@@ -32,7 +32,8 @@ from . import errors as err
 from . import ir, physical as phys
 from . import physical_plan as pp
 from ..kernels import registry as kreg
-from .dtypes import NULL_CODE, categories_of, is_category, physical_dtype
+from .dtypes import (NULL_CODE, categories_of, is_category, logical_key,
+                     physical_dtype)
 from .expr import ExternalArray, evaluate, nulltag_for
 from .spans import timed
 from .table import DTable, pad_to
@@ -652,7 +653,10 @@ class Lowered:
         for re-executing a cached trace over a different same-shape table.
         For persisted device scans the substitute must carry a device layout
         with the same shard count and capacity, so the shard_map signature
-        (and hence the compiled executable) is reused byte-identical.
+        (and hence the compiled executable) is reused byte-identical.  The
+        substitute's columns must carry the same logical dtypes: a string
+        predicate was rewritten into one dictionary's codes, which mean
+        other strings under another dictionary.
         """
         if self.platform == "tpu" and \
                 "interpret" in self.kernels.kernel_modes.values():
@@ -671,6 +675,8 @@ class Lowered:
         inputs = {"scans": {}, "ext": {}, "rows": {}}
         for s in self.scans:
             sub = scan_nodes.get(str(s.id)) if scan_nodes else None
+            if sub is not None:
+                _check_rebind_dtypes(s, sub)
             overridden = scan_arrays is not None and str(s.id) in scan_arrays
             src = scan_arrays[str(s.id)] if overridden else (
                 sub.columns if sub is not None else s.columns)
@@ -784,13 +790,18 @@ class Lowered:
         return self._op_scopes
 
     def __call__(self, scan_arrays: dict[str, dict[str, np.ndarray]] | None = None,
-                 scan_nodes=None, phases: dict | None = None):
+                 scan_nodes=None, phases: dict | None = None,
+                 shard_rows: dict | None = None):
         """Execute.  scan_arrays overrides source columns by scan id (str);
         scan_nodes rebinds scans to other same-shape tables (plan cache).
         ``phases`` (if given) gains the seconds of ``prepare`` (inputs
         placed on the device), ``run`` (dispatch until the overflow flags
         are on the host) and ``check`` (the host-side overflow and
-        invariant checks); each is also the host span ``hf.<phase>``."""
+        invariant checks); each is also the host span ``hf.<phase>``.
+        ``shard_rows`` (if given) gains, for each capacity site
+        ``"<kind>.<op id>"``, the tuple of each shard's requirement estimate
+        that the overflow check reads back anyway (no extra transfer): rows,
+        or for a join the product of its two inputs' rows."""
         with timed("prepare", phases):
             fn, inputs = self._prepare(scan_arrays, scan_nodes)
         with timed("run", phases):
@@ -798,6 +809,10 @@ class Lowered:
             flags = np.asarray(out["overflow"]).reshape(self.P, -1)
         with timed("check", phases):
             reqs = np.asarray(out["ovf_req"]).reshape(self.P, -1)
+            if shard_rows is not None:
+                for i, (op_id, kind, _, _) in enumerate(self.sites):
+                    shard_rows[f"{kind}.{op_id}"] = tuple(
+                        int(v) for v in reqs[:, i])
             overflow_ops = self._attribute_overflow(flags, reqs)
             failures = self._check_invariants(out, overflow_ops)
             return DTable(columns=out["cols"], counts=out["count"],
@@ -895,6 +910,19 @@ class Lowered:
                     fails.append(err.InvariantFailure(
                         kind, opid, f"{detail}: in={a} out={b}"))
         return tuple(fails)
+
+
+def _check_rebind_dtypes(s: ir.Scan, sub: ir.Scan) -> None:
+    """Refuse a rebind whose substitute gives a column of ``s`` another
+    logical dtype (another dictionary, nullability or width)."""
+    want, got = s.schema, sub.schema
+    moved = [c for c in s.columns
+             if c in got and logical_key(got[c]) != logical_key(want[c])]
+    if moved:
+        raise ValueError(
+            f"scan rebind for {s.name!r}: columns {moved} carry other "
+            "logical dtypes than the plan was built for (a dictionary's "
+            "codes mean other strings under another)")
 
 
 # an HLO instruction line whose op_name holds an operator's named scope

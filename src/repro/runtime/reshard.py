@@ -40,6 +40,7 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from ..core import ir
+from ..core.dtypes import carry_logical
 
 
 def _index_map(counts: np.ndarray, cap_old: int, P_new: int
@@ -119,7 +120,8 @@ def reshard(df, P_new: int, cfg=None, *, reestablish: bool = True,
         partitioned_by=() if keep_part else lay.partitioned_by,
         counts=counts_new, capacity=int(cap_new), nshards=P_new,
         dist="1D_VAR")
-    scan = ir.Scan(name or f"{node.name}@P{P_new}", cols, layout=new_lay)
+    scan = ir.Scan(name or f"{node.name}@P{P_new}", cols,
+                   carry_logical(cols, node.schema), layout=new_lay)
     out = DataFrame(scan)
     if keep_part and reestablish and cfg is not None and lay.kind == "hash":
         # one on-device hash exchange re-establishes the partitioning claim

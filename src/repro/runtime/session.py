@@ -109,7 +109,12 @@ class QueryRecord:
     host; ``check_s`` the host-side overflow and invariant checks.
     ``exec_s`` is ``lock_s + prepare_s + run_s + check_s``, summed over
     every execution of the query (a hit that falls back, retries).
-    ``DTable.to_numpy`` adds ``readback_s`` and ``readback_bytes``."""
+    ``DTable.to_numpy`` adds ``readback_s`` and ``readback_bytes``.
+    ``shard_rows`` maps each capacity site of the plan (``"<kind>.<op
+    id>"``, e.g. ``"join.4"``) to each shard's requirement estimate (rows;
+    for a join the product of its inputs' rows), as the overflow check of
+    the execution that answered read it back: how evenly the work fell
+    over the shards, at no extra transfer."""
 
     qid: int
     fingerprint: str
@@ -126,6 +131,7 @@ class QueryRecord:
     collectives: int = 0            # plan's all_to_all count per execution
     compiles: int = 0               # NEW jit entries this query caused
     events: tuple = ()
+    shard_rows: dict = field(default_factory=dict)
     # the compiled plan that produced the answer (op_scopes(), hlo_text())
     plan: Optional[Lowered] = field(default=None, repr=False, compare=False)
 
@@ -390,15 +396,18 @@ class Session:
 
     def _execute(self, lowered: Lowered, rec: QueryRecord, **kw):
         """Run ``lowered`` under the mesh lock; the wait for the lock and
-        the execution's phases are added to ``rec``."""
+        the execution's phases are added to ``rec``, and its per-shard
+        requirements replace ``rec.shard_rows``."""
         phases: dict = {}
+        shard_rows: dict = {}
         with timed("lock", phases):
             self._mesh_lock.acquire()
         try:
-            return lowered(phases=phases, **kw)
+            return lowered(phases=phases, shard_rows=shard_rows, **kw)
         finally:
             self._mesh_lock.release()
             rec.add_exec(phases)
+            rec.shard_rows = shard_rows
 
     def _try_hit(self, lowered: Lowered, scan_nodes, rec: QueryRecord):
         """Replay the cached executable with this query's scan buffers.
